@@ -644,4 +644,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     main()

@@ -1,5 +1,7 @@
-"""Kernel microbenchmarks: Pallas kernels (interpret mode — CPU wall time
-is NOT TPU latency; reported for relative sanity only) plus the analytical
+"""Kernel microbenchmarks: Pallas kernels (compiled on a TPU; on the CPU
+they run in interpret mode, and that wall time is NOT TPU latency — rows
+then carry `interp` in their names and the JSON `backend` says "cpu")
+plus the analytical
 TPU latencies the DSE actually uses (modeled compute/memory terms), plus —
 the number the packed-residency work is about — the modeled HBM bytes each
 launch moves (`hbm_mb`): W4 packed streams half the weight bytes of the
@@ -46,6 +48,8 @@ def main(argv=None):
             row["hbm_mb"] = round(hbm_mb, 3)
         rows.append(row)
 
+    mode = "tpu" if ops.on_tpu() else "interp"
+    interpret = not ops.on_tpu()
     key = jax.random.PRNGKey(0)
     cases = [
         ("paper512", 512, 512, 512, 128),
@@ -65,9 +69,9 @@ def main(argv=None):
         for wl, wq in ((8, wq8), (4, wq4)):
             tag = f"W{wl}" + ("_packed" if wq.packed else "")
             dt, _ = timed(lambda: ops.qmm(x, wq, use_kernel=True,
-                                          interpret=True), iters=1)
+                                          interpret=interpret), iters=1)
             qmm_mb[wl] = ops.qmm_hbm_bytes(m, wq) / 2**20
-            record(f"kernel_qmm_interp_{tag}_{name}", dt * 1e6,
+            record(f"kernel_qmm_{mode}_{tag}_{name}", dt * 1e6,
                    f"M={m};K={k};N={n}", hbm_mb=qmm_mb[wl])
         lrmm_mb = {}
         for wl, lr in ((8, lr8), (4, lr4)):
@@ -76,9 +80,9 @@ def main(argv=None):
             tag = f"W{wl}" + ("_packed" if (lr.w1.packed or lr.w2.packed)
                               else "")
             dt, _ = timed(lambda: ops.lrmm(x, lr, use_kernel=True,
-                                           interpret=True), iters=1)
+                                           interpret=interpret), iters=1)
             lrmm_mb[wl] = ops.lrmm_hbm_bytes(m, lr) / 2**20
-            record(f"kernel_lrmm_interp_{tag}_{name}", dt * 1e6,
+            record(f"kernel_lrmm_{mode}_{tag}_{name}", dt * 1e6,
                    f"M={m};K={k};N={n};R={r}", hbm_mb=lrmm_mb[wl])
         # packing must never lose to its own carrier: the W4 launch (with
         # ops.packed_pad_ok demoting pad-inflating axes) streams at most
@@ -131,18 +135,18 @@ def main(argv=None):
         for kv_bits, tag in ((16, "bf16kv"), (8, "int8kv")):
             if kv_bits == 8:
                 pool_l = {
-                    "k": jax.random.randint(ks[1], (nb_pool, bs, hk, dh),
+                    "k": jax.random.randint(ks[1], (nb_pool, hk, bs, dh),
                                             -127, 128).astype(jnp.int8),
-                    "v": jax.random.randint(ks[2], (nb_pool, bs, hk, dh),
+                    "v": jax.random.randint(ks[2], (nb_pool, hk, bs, dh),
                                             -127, 128).astype(jnp.int8),
-                    "ks": jnp.ones((nb_pool, bs, hk, 1), jnp.float32) * 0.02,
-                    "vs": jnp.ones((nb_pool, bs, hk, 1), jnp.float32) * 0.02,
+                    "ks": jnp.ones((nb_pool, hk, bs, 1), jnp.float32) * 0.02,
+                    "vs": jnp.ones((nb_pool, hk, bs, 1), jnp.float32) * 0.02,
                 }
             else:
                 pool_l = {
-                    "k": jax.random.normal(ks[1], (nb_pool, bs, hk, dh),
+                    "k": jax.random.normal(ks[1], (nb_pool, hk, bs, dh),
                                            jnp.bfloat16),
-                    "v": jax.random.normal(ks[2], (nb_pool, bs, hk, dh),
+                    "v": jax.random.normal(ks[2], (nb_pool, hk, bs, dh),
                                            jnp.bfloat16),
                 }
             geom = f"B={B};W={W};Hk={hk};G={g};Dh={dh};bs={bs};MB={mb}"
@@ -153,8 +157,8 @@ def main(argv=None):
                                             kv_bits=kv_bits, w=W,
                                             n_q_heads=h) / 2**20
             dt, _ = timed(lambda: pa.paged_attention(
-                q, pool_l, bt_a, ctx_a, ql_a, interpret=True), iters=1)
-            record(f"kernel_pattn_interp_{tag}_{cname}_ctx", dt * 1e6,
+                q, pool_l, bt_a, ctx_a, ql_a, interpret=interpret), iters=1)
+            record(f"kernel_pattn_{mode}_{tag}_{cname}_ctx", dt * 1e6,
                    geom, hbm_mb=stream_mb)
             pos = ctx_a[:, None] + jnp.arange(W)[None, :]
             dt, _ = timed(lambda: mattn._span_attend_gather(
@@ -187,4 +191,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     main()

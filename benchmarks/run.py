@@ -37,10 +37,15 @@ def main() -> None:
         # reclaimable in-process and hundreds of compiles across benches
         # otherwise exhaust it
         import subprocess
+        # the children share one compile cache: JAX_COMPILATION_CACHE_DIR
+        # where it is set, else the checkout's .jax_cache/
+        # (repro.launch.compile_cache; this parent stays off jax)
         r = subprocess.run(
             [sys.executable, os.path.join(here, name + ".py")],
             text=True, capture_output=True,
-            env={**os.environ,
+            env={"JAX_COMPILATION_CACHE_DIR": os.path.join(
+                     here, os.pardir, ".jax_cache"),
+                 **os.environ,
                  "PYTHONPATH": os.pathsep.join(
                      [os.path.join(here, "..", "src"), here,
                       os.environ.get("PYTHONPATH", "")])})

@@ -6,7 +6,8 @@
    — uniform quant / svd / itera, plus a mixed W4-attention / W8-MLP plan
    the legacy single-method config could not express.
 3. Serve the compressed model through the InferenceEngine facade.
-4. Run the fused cascade Pallas kernel (interpret mode) against its oracle.
+4. Run the fused cascade Pallas kernel against its oracle (compiled on a
+   TPU, interpret mode elsewhere).
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -72,10 +73,10 @@ def main():
     print(f"  generated {res.tokens.shape} "
           f"({res.tokens_per_second:.1f} tok/s): {res.tokens[0].tolist()}")
 
-    print("== 4. Fused cascade kernel vs oracle (interpret mode) ==")
+    print("== 4. Fused cascade kernel vs oracle ==")
     x = jax.random.normal(key, (64, 512))
     lr = itera_decompose(llm_like(key, 512, 512) / 22.0, 128, 6)
-    y_k = ops.lrmm(x, lr, use_kernel=True, interpret=True)
+    y_k = ops.lrmm(x, lr, use_kernel=True)
     y_r = ops.lrmm(x, lr, use_kernel=False)
     print(f"  kernel vs oracle max|diff| = "
           f"{float(jnp.max(jnp.abs(y_k - y_r))):.2e}")

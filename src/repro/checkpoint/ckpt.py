@@ -23,9 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.itera import LowRankQ      # noqa: F401  (registers pytree
-from repro.core.quant import QuantizedTensor  # noqa: F401   nodes appearing
-                                              # in compressed checkpoints)
+# importing LowRankQ registers the pytree node compressed checkpoints hold
+from repro.core.itera import LowRankQ      # noqa: F401
+from repro.core.quant import PACK_LAYOUT, QuantizedTensor
 
 _SEP = "|"
 
@@ -50,20 +50,26 @@ def _part(p):
 
 
 def _quant_formats(tree) -> dict:
-    """{path: {wl, axis, packed, act_wl}} for every QuantizedTensor node.
+    """{path: {wl, axis, packed, pack_layout, act_wl}} for every
+    QuantizedTensor node.
 
     The codes/scales land in arrays.npz like any leaf; this records the
     *layout* aux alongside them so the manifest is self-describing and
     restore can refuse a tree built with the wrong residency (a packed-W4
     checkpoint restored into a carrier-layout tree, or vice versa, would
-    otherwise only surface as a confusing shape error)."""
+    otherwise only surface as a confusing shape error). `pack_layout`
+    names the nibble order of packed codes (core.quant.PACK_LAYOUT):
+    packed bytes of another layout have the same shape and would restore
+    into silently wrong weights."""
     fmts = {}
 
     def visit(path, leaf):
         if isinstance(leaf, QuantizedTensor):
             fmts[_SEP.join(_part(p) for p in path)] = {
                 "wl": int(leaf.wl), "axis": int(leaf.axis),
-                "packed": bool(leaf.packed), "act_wl": int(leaf.act_wl),
+                "packed": bool(leaf.packed),
+                "pack_layout": PACK_LAYOUT if leaf.packed else None,
+                "act_wl": int(leaf.act_wl),
             }
         return leaf
 
@@ -161,15 +167,16 @@ def restore(ckpt_dir: str, like, step: int | None = None, *,
                        f"{missing[:5]}{'...' if len(missing) > 5 else ''}")
 
     # layout guard: quantized nodes must agree on the fields that shape
-    # the stored arrays (wl, axis, packed) — restoring a packed
-    # checkpoint into a carrier tree (or the reverse) is a plan mismatch,
-    # not an elastic-resume case. act_wl is runtime-only aux (it never
-    # changes resident bytes), so differing act_wl restores fine and
-    # `like`'s value wins.
+    # or order the stored arrays (wl, axis, packed, pack_layout) —
+    # restoring a packed checkpoint into a carrier tree (or the reverse)
+    # is a plan mismatch, not an elastic-resume case, and packed bytes
+    # saved before pack_layout was recorded use an older nibble order.
+    # act_wl is runtime-only aux (it never changes resident bytes), so
+    # differing act_wl restores fine and `like`'s value wins.
     saved_fmts = manifest.get("quant_formats")
     if saved_fmts is not None:
         want_fmts = _quant_formats(like)
-        layout = ("wl", "axis", "packed")
+        layout = ("wl", "axis", "packed", "pack_layout")
         for key in sorted(set(saved_fmts) & set(want_fmts)):
             got = {f: saved_fmts[key].get(f) for f in layout}
             want = {f: want_fmts[key].get(f) for f in layout}

@@ -216,9 +216,9 @@ def shape_spectra(params, alpha: float = 2.0,
 
 def _runtime_format(node, act_wl: int, pack: bool):
     """Stamp the plan's runtime knobs onto a compressed node: the
-    activation word length its matmul quantizes to, and — for W4 with an
-    even, non-pad-inflating last dim (`quant.packable`) — the
-    packed-nibble HBM layout. Packing is exact (codes unchanged), so
+    activation word length its matmul quantizes to, and — for W4 whose
+    last dim is whole pack tiles (`quant.packable`) — the packed-nibble
+    HBM layout. Packing is exact (codes unchanged), so
     packed and carrier trees are token-identical."""
     def one(q: QuantizedTensor) -> QuantizedTensor:
         q = dataclasses.replace(q, act_wl=act_wl)

@@ -140,62 +140,77 @@ def quant_linear_ref(x: Array, w: Array, w_wl: int, a_wl: int) -> Array:
     return xq @ qw.dequant()
 
 
+# Packed-W4 byte layout, by tiles of PACK_TILE columns along the last
+# axis: byte j of a tile holds column j in its low nibble and column
+# j + PACK_TILE/2 in its high nibble. A kernel then unpacks each
+# 128-byte half-tile with two shifts and puts the halves side by side on
+# 128-lane boundaries — no lane interleave. Checkpoints record this name
+# and refuse packed arrays written under any other layout.
+PACK_TILE = 256
+PACK_LAYOUT = "int4-halves-256"
+
+
 def pack_int4(codes: Array) -> Array:
     """Pack int8-carried int4 codes into bytes (two nibbles per byte).
 
-    This IS the HBM layout for packed W4 weights: element 2i goes to the
-    low nibble of byte i, element 2i+1 to the high nibble (matching the
-    in-kernel unpack in kernels/quant_matmul.py). The last dim must be
-    even. Values must be in [-8, 7].
+    This IS the HBM layout for packed W4 weights (see PACK_TILE): within
+    each tile of width t, column j goes to the low nibble and column
+    j + t/2 to the high nibble of the tile's byte j. Only whole
+    PACK_TILE tiles are what the kernels read (`packed_pad_ok`); a
+    narrower (even) last tile round-trips through `unpack_int4` alone.
+    The last dim must be even. Values must be in [-8, 7].
     """
-    if codes.shape[-1] % 2:
+    *lead, n = codes.shape
+    if n % 2:
         raise ValueError(
             f"pack_int4 needs an even last dim, got shape {codes.shape}")
-    lo = codes[..., 0::2] & 0x0F
-    hi = (codes[..., 1::2] & 0x0F) << 4
-    return (lo | hi).astype(jnp.int8)
+    full = n - n % PACK_TILE
+    tiles = codes[..., :full].reshape(*lead, full // PACK_TILE, 2,
+                                      PACK_TILE // 2)
+    tail = codes[..., full:]
+    lo = jnp.concatenate([tiles[..., 0, :].reshape(*lead, full // 2),
+                          tail[..., :tail.shape[-1] // 2]], axis=-1)
+    hi = jnp.concatenate([tiles[..., 1, :].reshape(*lead, full // 2),
+                          tail[..., tail.shape[-1] // 2:]], axis=-1)
+    return ((lo & 0x0F) | ((hi & 0x0F) << 4)).astype(jnp.int8)
 
 
 def unpack_int4(packed: Array) -> Array:
     """Inverse of pack_int4 (sign-extends each nibble)."""
-    lo = (packed & 0x0F).astype(jnp.int8)
-    hi = ((packed.astype(jnp.int32) >> 4) & 0x0F).astype(jnp.int8)
-
-    def sext(v):
-        return jnp.where(v >= 8, v - 16, v)
-
-    out = jnp.stack([sext(lo), sext(hi)], axis=-1)
-    return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
+    *lead, c = packed.shape
+    p32 = packed.astype(jnp.int32)
+    lo = ((p32 << 28) >> 28).astype(jnp.int8)     # sign-extended low nibble
+    hi = ((p32 << 24) >> 28).astype(jnp.int8)     # sign-extended high nibble
+    half = PACK_TILE // 2
+    full = c - c % half                           # bytes of whole tiles
+    tiles = jnp.stack([lo[..., :full].reshape(*lead, full // half, half),
+                       hi[..., :full].reshape(*lead, full // half, half)],
+                      axis=-2)
+    return jnp.concatenate([tiles.reshape(*lead, 2 * full),
+                            lo[..., full:], hi[..., full:]], axis=-1)
 
 
 def packed_pad_ok(dim: int) -> bool:
-    """Whether nibble-packing a `dim`-wide axis is free of padding
-    inflation in the Pallas kernels: a packed half-width must stay
-    128-lane aligned, so a packed axis pads to a multiple of 256 where
-    its int8 carrier pads to 128. When the two round-ups differ (dim %
-    256 in 1..128 — e.g. a rank-128 cascade factor, or the smoke model's
-    64-wide heads), packing buys nothing at runtime: the kernel streams
-    the same padded bytes as the carrier but runs double the padded MXU
-    work (the old `kernel_lrmm_interp_W4_packed_paper512` regression).
-    Such axes stay int8 carriers — `packable` gates on this, so the
-    decision is made ONCE at pack time, not paid per dispatch."""
-    return -(-dim // 256) * 256 == -(-dim // 128) * 128
+    """Whether a `dim`-wide axis packs into the layout the Pallas kernels
+    read: whole PACK_TILE tiles. Padding then adds whole zero tiles,
+    which unpack to zero codes, and every packed block is 128-lane
+    aligned. Other widths stay int8 carriers — `packable` gates on this,
+    so the decision is made ONCE at pack time, not paid per dispatch."""
+    return dim % PACK_TILE == 0
 
 
 def packable(q: QuantizedTensor) -> bool:
     """True when `q` can move to the packed-nibble layout: W4 codes (the
-    only word length whose packing is byte-aligned) with an even last
-    dim whose packed padding does not exceed its carrier's
-    (`packed_pad_ok`), not already packed."""
+    only word length whose packing is byte-aligned) whose last dim is
+    whole pack tiles (`packed_pad_ok`), not already packed."""
     return (not q.packed and q.wl == 4
-            and int(q.values.shape[-1]) % 2 == 0
             and packed_pad_ok(int(q.values.shape[-1])))
 
 
 def pack_weights(q: QuantizedTensor) -> QuantizedTensor:
     """Move a W4 tensor to the packed HBM-resident layout (exact: the
     codes are unchanged, only the byte layout differs). Non-packable
-    tensors (W6/W8, odd last dim, pad-inflating last dim) are returned
+    tensors (W6/W8, a last dim that is not whole pack tiles) are returned
     as-is — they stay int8 carriers and `storage_bits()` charges them
     the full 8 bits."""
     if not packable(q):

@@ -34,10 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant_matmul import unpack_int4_block
-
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels.quant_matmul import int8_dot, tile_bytes, unpack_int4_block
 
 
 def _kernel(
@@ -56,11 +53,7 @@ def _kernel(
     @pl.when(s < k_blocks)
     def _accum():
         w1 = unpack_int4_block(w1_ref[...]) if w1_packed else w1_ref[...]
-        tacc_ref[...] += jax.lax.dot_general(
-            xq_ref[...], w1,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        tacc_ref[...] += int8_dot(xq_ref[...], w1)
 
     # ---- phase boundary: dequant, fold s2, requantize per row to int8 -----
     @pl.when(s == k_blocks)
@@ -77,11 +70,7 @@ def _kernel(
     @pl.when(s >= k_blocks)
     def _emit():
         w2 = unpack_int4_block(w2_ref[...]) if w2_packed else w2_ref[...]
-        acc = jax.lax.dot_general(
-            tq_ref[...], w2,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        acc = int8_dot(tq_ref[...], w2)
         o_ref[...] = (acc.astype(jnp.float32) * st_ref[...]).astype(o_ref.dtype)
 
 
@@ -167,31 +156,32 @@ def lowrank_qmm(
             pltpu.VMEM((bm, r), jnp.int8),    # requantized T
             pltpu.VMEM((bm, 1), jnp.float32), # per-row T scale
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="lowrank_qmm",
     )(xq, sx, w1q, s1, w2q, s2)
 
 
 def vmem_bytes(bm: int, bk: int, bn: int, r: int, *,
                w1_packed: bool = False, w2_packed: bool = False) -> int:
-    """VMEM working set of one grid step (constraint for the DSE). Packed
-    factor blocks DMA half the bytes but add a transient unpacked int8
-    copy for the MXU (1.5x the carrier block on-chip — packing buys HBM
-    bandwidth, not VMEM)."""
-    w1_blk = (bk * r // 2 + bk * r) if w1_packed else bk * r
-    w2_blk = (r * bn // 2 + r * bn) if w2_packed else r * bn
-    return (
-        bm * bk          # x block int8
-        + w1_blk         # w1 block (packed DMA + unpacked temp, or carrier)
-        + w2_blk         # w2 block
-        + bm * r * 4     # T accumulator int32
-        + bm * r         # Tq int8
-        + bm * 4 * 2     # sx, st
-        + r * 4 * 2      # s1, s2
-        + bm * bn * 4    # out f32
-    )
+    """Scoped VMEM one launch needs (constraint for the DSE), counted
+    like `quant_matmul.vmem_bytes`: double-buffered tile-padded blocks,
+    the T scratch, and one int8 MXU operand per factor (the unpacked
+    copy when packed — packing buys HBM bandwidth, not VMEM)."""
+    blocks = (tile_bytes(bm, bk, 1)                             # xq
+              + tile_bytes(bm, 1, 4)                            # sx
+              + tile_bytes(bk, r // 2 if w1_packed else r, 1)   # w1
+              + tile_bytes(1, r, 4)                             # s1
+              + tile_bytes(r, bn // 2 if w2_packed else bn, 1)  # w2
+              + tile_bytes(r, 1, 4)                             # s2
+              + tile_bytes(bm, bn, 4))                          # out f32
+    scratch = (tile_bytes(bm, r, 4)          # T accumulator int32
+               + tile_bytes(bm, r, 1)        # Tq int8
+               + tile_bytes(bm, 1, 4))       # per-row T scale
+    operands = tile_bytes(bk, r, 1) + tile_bytes(r, bn, 1)
+    return 2 * blocks + scratch + operands
 
 
 def hbm_bytes_moved(m: int, k: int, n: int, r: int, bm: int, *,

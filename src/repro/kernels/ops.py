@@ -6,15 +6,20 @@ dispatch (packed arrays DMA as-is; the kernels unpack in VMEM), padding to
 block multiples (zero bytes unpack to zero codes, so padding happens
 directly in the packed domain), automatic block-shape selection under a
 VMEM budget (the DSE's per-layer choice — see hw/dse.py for the global
-search), and backend dispatch:
+search), and backend dispatch.
 
-  * on TPU           -> compiled Pallas kernels
-  * on CPU (tests)   -> interpret=True Pallas (bit-faithful emulation)
-  * use_kernel=False -> pure-jnp reference path (used inside big jitted
-                        models / dry-runs, where interpret-mode Pallas would
-                        bloat the HLO; numerically identical to the kernels
-                        — packed weights are unpacked up front, which is
-                        exact)
+`on_tpu()` is the one platform predicate of the package: it decides
+whether a kernel runs compiled or in interpret mode here, whether
+`models.apply_linear` ("auto") takes the kernels or the jnp reference,
+and whether serving attention takes the paged-attention kernel
+(`models.attention._paged_impl`). On the TPU everything compiles; on the
+CPU the served path runs the jnp reference and the kernel tests run the
+same kernels in interpret mode (bit-faithful emulation). Tests steer it
+with monkeypatch, never through a program option.
+
+  * use_kernel=False -> pure-jnp reference path (numerically identical
+                        to the kernels — packed weights are unpacked up
+                        front, which is exact)
 """
 from __future__ import annotations
 
@@ -31,7 +36,9 @@ from repro.kernels import lowrank_qmm as _lr
 from repro.kernels import quant_matmul as _qm
 from repro.kernels import ref as _ref
 
-VMEM_BUDGET = 12 * 1024 * 1024  # leave headroom below the 16 MiB/core VMEM
+# 16 MiB is the TPU compiler's default scoped-VMEM limit per kernel on
+# v5e (not the chip's VMEM size); blocks are chosen under 12 MiB of it.
+VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _round_up(x: int, m: int) -> int:
@@ -39,6 +46,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 def on_tpu() -> bool:
+    """True where the Pallas kernels compile (see module docstring)."""
     return jax.default_backend() == "tpu"
 
 
@@ -51,14 +59,14 @@ def quantize_acts(x: jax.Array, qm: int = 127):
     return xq, sx
 
 
-# Pad-inflating pack axes (core.quant.packed_pad_ok false — e.g. the
-# paper512 cascade's R=128, once `kernel_lrmm_interp_W4_packed_paper512`'s
-# 11297us-vs-6379us regression) are refused at PACK time: compress_params
-# stores them as int8 carriers, so the dispatchers below normally never
-# see them. The demotion branches in qmm/lrmm are a fallback for
-# hand-built packed tensors only — they unpack per call (exact, so still
-# bit-identical), and the *_hbm_bytes models charge that unpack
-# round-trip so the benchmark's packed<=carrier assert stays honest.
+# Pack axes that are not whole pack tiles (core.quant.packed_pad_ok
+# false — e.g. the paper512 cascade's R=128) are refused at PACK time:
+# compress_params stores them as int8 carriers, so the dispatchers below
+# normally never see them. The demotion branches in qmm/lrmm are a
+# fallback for hand-built packed tensors only — they unpack per call
+# (exact, so still bit-identical), and the *_hbm_bytes models charge that
+# unpack round-trip so the benchmark's packed<=carrier assert stays
+# honest.
 
 
 def choose_blocks(m: int, k: int, n: int, r: int | None = None,
@@ -69,8 +77,9 @@ def choose_blocks(m: int, k: int, n: int, r: int | None = None,
     Mirrors the paper's hardware-aware tile selection: prefer large bm/bn
     (amortize weight streaming), shrink until the working set fits.
     packed_n: the N-axis operand (W, or W2 in the cascade) is
-    nibble-packed, so bn stays >= 256 (the packed half-block must remain
-    lane-aligned) and the working set counts the unpack temp. packed_r:
+    nibble-packed, so bn stays a multiple of 256 (whole pack tiles, each
+    unpacking from a lane-aligned 128-byte half) and the working set
+    counts the unpacked operand. packed_r:
     the cascade's W1 is packed along R (affects only the vmem model; R is
     never tiled). Callers must only set packed_* for axes where
     `packed_pad_ok` holds — qmm/lrmm demote the rest to carrier first —

@@ -60,9 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG = -2.3819763e38  # large negative for masking in f32 (models.attention)
 
 
@@ -85,13 +82,13 @@ def _kernel(nb_ref, bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j < nb)
     def _block():
         q = q_ref[0, 0]                                   # (WG, Dh)
-        k = k_ref[0, :, 0, :]                             # (bs, Dh)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                                   # (bs, Dh)
+        v = v_ref[0, 0]
         if ks_ref is not None:
             # in-VMEM dequant right before the dot, mirroring the oracle's
             # `codes.astype(q.dtype) * scales.astype(q.dtype)` order
-            k = k.astype(q.dtype) * ks_ref[0, :, 0, :].astype(q.dtype)
-            v = v.astype(q.dtype) * vs_ref[0, :, 0, :].astype(q.dtype)
+            k = k.astype(q.dtype) * ks_ref[0, 0].astype(q.dtype)
+            v = v.astype(q.dtype) * vs_ref[0, 0].astype(q.dtype)
         s = jax.lax.dot_general(                          # (WG, bs) f32
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -133,7 +130,7 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
 
     q: (B, W, H, Dh) post-RoPE queries (row r valid in [:q_lens[r]]);
     pool: ONE layer's blocks {"k","v"[,"ks","vs"]} with leaves
-    (NB, bs, Hk, *) — already holding this step's scattered span K/V;
+    (NB, Hk, bs, *) — already holding this step's scattered span K/V;
     block_table: (B, MB) int32; ctx_lens / q_lens: (B,) int32.
 
     Returns (B, W, H, Dh) in q.dtype: attention output at every span
@@ -142,7 +139,7 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
     [:q_lens[r]] of every active row. Rows with q_lens == 0 return zeros.
     """
     b, w, h, dh = q.shape
-    _, bs, hk, _ = pool["k"].shape
+    _, hk, bs, _ = pool["k"].shape
     mb = block_table.shape[1]
     g = h // hk
     wg = w * g
@@ -165,16 +162,18 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
         # map returns the same physical block as the previous step, so the
         # pipeline skips the DMA — only valid context ever streams
         jj = jnp.maximum(jnp.minimum(j, nb_[r] - 1), 0)
-        return (bt_[r, jj], 0, h_, 0)
+        return (bt_[r, jj], h_, 0, 0)
 
+    # one (bs, Dh) tile of one head per step: the block's last two dims
+    # are the pool's own, which the TPU's tiling accepts at any bs
     kv_specs = [
-        pl.BlockSpec((1, bs, 1, dh), kv_map),
-        pl.BlockSpec((1, bs, 1, dh), kv_map),
+        pl.BlockSpec((1, 1, bs, dh), kv_map),
+        pl.BlockSpec((1, 1, bs, dh), kv_map),
     ]
     operands = [qh, pool["k"], pool["v"]]
     if quant:
-        kv_specs += [pl.BlockSpec((1, bs, 1, 1), kv_map),
-                     pl.BlockSpec((1, bs, 1, 1), kv_map)]
+        kv_specs += [pl.BlockSpec((1, 1, bs, 1), kv_map),
+                     pl.BlockSpec((1, 1, bs, 1), kv_map)]
         operands += [pool["ks"], pool["vs"]]
 
     def kernel(*refs):
@@ -203,10 +202,11 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, wg, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_attention",
     )(nb, bt, ctx_lens.astype(jnp.int32), *operands)
     return (out.reshape(b, hk, w, g, dh).transpose(0, 2, 1, 3, 4)
             .reshape(b, w, h, dh))

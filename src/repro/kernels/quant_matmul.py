@@ -26,23 +26,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.core.quant import PACK_TILE
+
+_HALF = PACK_TILE // 2
 
 
 def unpack_int4_block(wp):
     """Sign-extend a packed-nibble int8 block (B, C) -> int8 codes (B, 2C).
 
-    Shift arithmetic runs in int32 (Mosaic lowers sub-word shifts through
-    32-bit lanes anyway, and interpret mode matches exactly): byte b holds
-    code 2i in bits 3..0 and code 2i+1 in bits 7..4, the layout written by
-    core.quant.pack_int4.
+    C is a multiple of 128: each 128-byte half-tile holds one
+    core.quant.pack_int4 tile, column j in the low nibble and column
+    j + 128 in the high nibble. Each half-tile unpacks with two int32
+    shift pairs (Mosaic lowers sub-word shifts through 32-bit lanes
+    anyway, and interpret mode matches exactly), and the two halves sit
+    side by side on 128-lane boundaries, so no lane ever moves. Working
+    half-tile by half-tile bounds the int32 temporaries to one
+    (B, 128) tile at a time.
     """
-    w32 = wp.astype(jnp.int32)
-    lo = (w32 << 28) >> 28                      # sign-extended low nibble
-    hi = (w32 << 24) >> 28                      # sign-extended high nibble
-    out = jnp.stack([lo, hi], axis=-1).astype(jnp.int8)
-    return out.reshape(*wp.shape[:-1], wp.shape[-1] * 2)
+    parts = []
+    for t in range(0, wp.shape[-1], _HALF):
+        w32 = wp[:, t:t + _HALF].astype(jnp.int32)
+        parts += [((w32 << 28) >> 28).astype(jnp.int8),     # low nibble
+                  ((w32 << 24) >> 28).astype(jnp.int8)]     # high nibble
+    return jnp.concatenate(parts, axis=-1)
+
+
+def int8_dot(a, b):
+    """(M, K) @ (K, N) int8 codes -> int32 on the MXU. Integer products
+    are exact at any precision, so the precision is pinned: a global
+    `jax_default_matmul_precision` of "highest" would otherwise ask
+    Mosaic for an f32 contraction, which it refuses for int8 operands."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.int32)
 
 
 def _kernel(xq_ref, sx_ref, wq_ref, sw_ref, o_ref, acc_ref, *, k_blocks,
@@ -54,11 +71,7 @@ def _kernel(xq_ref, sx_ref, wq_ref, sw_ref, o_ref, acc_ref, *, k_blocks,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     wq = unpack_int4_block(wq_ref[...]) if w_packed else wq_ref[...]
-    acc_ref[...] += jax.lax.dot_general(
-        xq_ref[...], wq,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    acc_ref[...] += int8_dot(xq_ref[...], wq)
 
     @pl.when(k == k_blocks - 1)
     def _finish():
@@ -118,29 +131,39 @@ def quant_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="quant_matmul",
     )(xq, sx, wq, sw)
 
 
-def vmem_bytes(bm: int, bk: int, bn: int, *, w_packed: bool = False) -> int:
-    """VMEM working set of one grid step (the BRAM analog, DESIGN.md §2).
+def tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) buffer in the TPU's native tiling:
+    rows pad to the dtype's sublane tile (8 for 4-byte, 32 for 1-byte
+    elements) and columns to 128 lanes."""
+    sub = 8 * (4 // itemsize)
+    return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
 
-    A packed weight block halves its DMA footprint but adds a transient
-    unpacked int8 copy for the MXU, so on-chip it costs 1.5x the carrier
-    block — the packing win is HBM bandwidth, not VMEM.
+
+def vmem_bytes(bm: int, bk: int, bn: int, *, w_packed: bool = False) -> int:
+    """Scoped VMEM one launch needs (the BRAM analog, DESIGN.md §2).
+
+    The Pallas pipeline double-buffers every input and output block, at
+    tile-padded size; the int32 accumulator is single scratch. One int8
+    (bk, bn) weight block on top: the unpacked copy the MXU reads when
+    the weight is packed, and the operand copy the compiler makes for
+    wide carrier blocks. Checked as an upper bound against the TPU
+    compiler (tests/test_tpu_compile.py compiles with exactly this
+    limit): packing halves the streamed block, not the MXU operand.
     """
-    w_blk = (bk * bn // 2 + bk * bn) if w_packed else bk * bn
-    return (
-        bm * bk            # x block int8
-        + w_blk            # w block (packed DMA + unpacked temp, or carrier)
-        + bm * 4           # sx
-        + bn * 4           # sw
-        + bm * bn * 4      # out f32
-        + bm * bn * 4      # acc int32
-    )
+    blocks = (tile_bytes(bm, bk, 1)                          # xq
+              + tile_bytes(bm, 1, 4)                         # sx
+              + tile_bytes(bk, bn // 2 if w_packed else bn, 1)  # wq
+              + tile_bytes(1, bn, 4)                         # sw
+              + tile_bytes(bm, bn, 4))                       # out f32
+    return 2 * blocks + tile_bytes(bm, bn, 4) + tile_bytes(bk, bn, 1)
 
 
 def hbm_bytes_moved(m: int, k: int, n: int, bm: int, bn: int,

@@ -102,8 +102,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
 
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax returns a list
-            ca = ca[0] if ca else {}
         hlo_text = compiled.as_text()
         hlo = hlo_analysis.analyze(hlo_text)
         try:  # cache the HLO so analyzer updates re-run without recompiling

@@ -14,13 +14,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the jax version has them
-    (jax.sharding.AxisType appeared after 0.4.x; Auto is the old default)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (GSPMD propagation decides
+    every sharding the annotations leave open)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -66,7 +63,7 @@ PEAK_OPS_INT8 = 394e12        # OP/s
 HBM_BW = 819e9                # B/s
 ICI_BW_PER_LINK = 50e9        # B/s per link
 ICI_LINKS = 4                 # v5e: 4 ICI links per chip (2D torus x2 dirs)
-VMEM_BYTES = 16 * 2 ** 20     # ~16 MiB/core wired scratchpad
+VMEM_BYTES = 16 * 2 ** 20     # compiler's default scoped VMEM per kernel
 HBM_BYTES = 16 * 2 ** 30      # 16 GiB HBM per v5e chip
 PCIE_BW = 16e9                # B/s host<->device (PCIe gen3 x16 effective)
 DISPATCH_S = 30e-6            # fixed host->device launch latency per dispatch

@@ -17,8 +17,9 @@ compiled engine.
   python -m repro.launch.serve --arch opus-mt --smoke --ragged \
       --batch 8 --max-batch 4 --block-size 16
 
-On CPU this runs the pure-jnp reference math; on TPU the same entry point
-dispatches the Pallas cascade kernels (models.set_linear_mode("auto")).
+On TPU the same entry point dispatches the compiled Pallas kernels
+(matmul cascades and paged attention); on CPU it runs the pure-jnp
+reference math (kernels.ops.on_tpu decides both).
 """
 from __future__ import annotations
 
@@ -280,4 +281,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_checkout_cache
+
+    use_checkout_cache()
     main()

@@ -297,8 +297,36 @@ def tp_spec_for(path: str, leaf, tp: int) -> P:
     return P(*spec)
 
 
+def _check_packed_slices(params, tp: int) -> None:
+    """Refuse column slices that cut packed W4 pack tiles.
+
+    Packed bytes hold whole 256-column tiles (core.quant.PACK_TILE), so a
+    column slice of a packed leaf is the packed form of its own logical
+    columns only when every shard holds whole tiles; otherwise a shard's
+    bytes would carry another shard's columns in their high nibbles."""
+    from repro.core.quant import PACK_TILE, QuantizedTensor
+
+    def visit(path, node):
+        if isinstance(node, QuantizedTensor) and node.packed:
+            p = path_str(path) + "/values"
+            sliced = tp_spec_for(p, node.values, tp)[-1] == "model"
+            cols = node.shape[-1] // tp
+            if sliced and cols % PACK_TILE:
+                raise ValueError(
+                    f"TP cannot column-slice packed {p}: each of the {tp} "
+                    f"shards would hold {cols} columns, not whole "
+                    f"{PACK_TILE}-column pack tiles — compress that site "
+                    f"unpacked (plan pack=False) or leave it out of the "
+                    f"plan")
+        return node
+
+    jax.tree_util.tree_map_with_path(
+        visit, params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
+
+
 def tp_param_specs(params, tp: int):
     """PartitionSpec pytree (shard_map in_specs) for the serving params."""
+    _check_packed_slices(params, tp)
     return jax.tree_util.tree_map_with_path(
         lambda p, l: tp_spec_for(path_str(p), l, tp), params)
 

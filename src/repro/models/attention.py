@@ -24,6 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models.layers import apply_linear, apply_rope, softcap
 
 NEG = -2.3819763e38  # large negative for masking in f32
@@ -230,14 +231,13 @@ def _fake_quant_kv(x):
 
 def _paged_impl(cfg) -> str:
     """Resolve cfg.paged_attn_impl: "auto" follows the matmul-kernel
-    dispatch rule — compiled Pallas on TPU, the jnp gather oracle on CPU
-    (interpret-mode Pallas inside the big jitted serving step would bloat
-    the HLO; the oracle is the numerics reference either way)."""
-    import jax as _jax
-
+    dispatch rule (`kernels.ops.on_tpu`) — compiled Pallas on TPU, the
+    jnp gather oracle on CPU (interpret-mode Pallas inside the big jitted
+    serving step would bloat the HLO; the oracle is the numerics
+    reference either way)."""
     impl = getattr(cfg, "paged_attn_impl", "auto")
     if impl == "auto":
-        return "kernel" if _jax.default_backend() == "tpu" else "ref"
+        return "kernel" if kops.on_tpu() else "ref"
     if impl not in ("kernel", "ref"):
         raise ValueError(f"paged_attn_impl must be auto|kernel|ref, "
                          f"got {impl!r}")
@@ -251,21 +251,17 @@ def _span_attend_gather(q, pool, block_table, pos, cfg):
     and a dense materialization regardless of ctx_lens — the cost the
     Pallas kernel exists to delete; kept as the selectable reference."""
     b, w = q.shape[:2]
-    hk, hd = cfg.num_kv_heads, cfg.head_dim
-    bs = pool["k"].shape[1]
+    hk, bs = pool["k"].shape[1:3]
     mb = block_table.shape[1]
+
+    def view(leaf):
+        # (NB, Hk, bs, *) -> this row's logical (B, MB*bs, Hk, *) view
+        g = leaf[block_table].transpose(0, 1, 3, 2, 4)
+        return g.reshape(b, mb * bs, hk, leaf.shape[-1]).astype(q.dtype)
+
+    ck, cv = view(pool["k"]), view(pool["v"])
     if "ks" in pool:
-        ck = (pool["k"][block_table].reshape(b, mb * bs, hk, hd)
-              .astype(q.dtype)
-              * pool["ks"][block_table].reshape(b, mb * bs, hk, 1)
-              .astype(q.dtype))
-        cv = (pool["v"][block_table].reshape(b, mb * bs, hk, hd)
-              .astype(q.dtype)
-              * pool["vs"][block_table].reshape(b, mb * bs, hk, 1)
-              .astype(q.dtype))
-    else:
-        ck = pool["k"][block_table].reshape(b, mb * bs, hk, hd).astype(q.dtype)
-        cv = pool["v"][block_table].reshape(b, mb * bs, hk, hd).astype(q.dtype)
+        ck, cv = ck * view(pool["ks"]), cv * view(pool["vs"])
 
     # (B, W, S): query (r, i) sees slots at positions <= ctx_lens[r] + i
     valid = jnp.arange(mb * bs)[None, None, :] <= pos[:, :, None]
@@ -274,7 +270,7 @@ def _span_attend_gather(q, pool, block_table, pos, cfg):
     s = jnp.where(valid[:, None, None, :, :], s, NEG)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(cv.dtype), cv)
-    return o.reshape(b, w, cfg.num_heads, hd)
+    return o.reshape(b, w, cfg.num_heads, cfg.head_dim)
 
 
 def span_attention_paged(params, x, pool, block_table, ctx_lens, q_lens,
@@ -289,14 +285,14 @@ def span_attention_paged(params, x, pool, block_table, ctx_lens, q_lens,
     positions and width 1 — so the same math serves both layouts.
 
     x: (B, W, D) hidden, row r valid in [:q_lens[r]]; pool: ONE layer's
-    blocks {"k","v"[,"ks","vs"]} with leaves (NB, bs, Hk, *);
+    blocks {"k","v"[,"ks","vs"]} with leaves (NB, Hk, bs, *);
     block_table: (B, MB) int32 physical block ids in logical order,
     padded with the reserved trash block 0; ctx_lens: (B,) int32 tokens
     already in the pool per row == the absolute position of x[:, 0]
     (per-row RoPE / mask).
 
     Span token (r, i) sits at position p = ctx_lens[r] + i. Its K/V is
-    scattered to (block_table[r, p // bs], p % bs) *first*, then
+    scattered to (block_table[r, p // bs], :, p % bs) *first*, then
     attention runs over the row's block-table view under the causal mask
     `slot <= p` — so queries see the pool prefix AND the earlier tokens
     of their own span, however the span is laid out (in-step causality
@@ -317,7 +313,7 @@ def span_attention_paged(params, x, pool, block_table, ctx_lens, q_lens,
 
     b, w, _ = x.shape
     h, hk, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    bs = pool["k"].shape[1]
+    bs = pool["k"].shape[2]
 
     q = apply_linear(x, params["wq"]).reshape(b, w, h, hd)
     k = apply_linear(x, params["wk"]).reshape(b, w, hk, hd)
@@ -328,20 +324,16 @@ def span_attention_paged(params, x, pool, block_table, ctx_lens, q_lens,
         k = apply_rope(k, pos, cfg.rope_theta, cfg.rotary_pct)
 
     blk, off = span_slots(block_table, ctx_lens, q_lens, w, bs)  # (B, W)
+    # (B, W, Hk, *) rows land at pool[blk, :, off]: the advanced indices
+    # straddle the head axis, so their (B, W) dims lead the update
     if "ks" in pool:
         kq, ks1 = _quant_kv(k)
         vq, vs1 = _quant_kv(v)
-        pool = {
-            "k": pool["k"].at[blk, off].set(kq),
-            "v": pool["v"].at[blk, off].set(vq),
-            "ks": pool["ks"].at[blk, off].set(ks1),
-            "vs": pool["vs"].at[blk, off].set(vs1),
-        }
+        new = {"k": kq, "v": vq, "ks": ks1, "vs": vs1}
     else:
-        pool = {
-            "k": pool["k"].at[blk, off].set(k.astype(pool["k"].dtype)),
-            "v": pool["v"].at[blk, off].set(v.astype(pool["v"].dtype)),
-        }
+        new = {"k": k, "v": v}
+    pool = {key: pool[key].at[blk, :, off].set(val.astype(pool[key].dtype))
+            for key, val in new.items()}
 
     impl = impl or _paged_impl(cfg)
     if impl == "kernel":
@@ -349,7 +341,7 @@ def span_attention_paged(params, x, pool, block_table, ctx_lens, q_lens,
 
         o = paged_attention(q, pool, block_table, ctx_lens, q_lens,
                             logit_softcap=cfg.logit_softcap,
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=not kops.on_tpu())
     else:
         o = _span_attend_gather(q, pool, block_table, pos, cfg)
     y = apply_linear(o.reshape(b, w, h * hd), params["wo"], reduce_tp=True)

@@ -384,7 +384,7 @@ def unified_step(params, pool, block_tables, ctx_lens, q_lens, inputs, cfg,
     inputs: (B, W) tokens, row r valid in [:q_lens[r]]; block_tables:
     (B, MB) int32; ctx_lens: (B,) int32 tokens already in the pool per
     row (== the position of inputs[:, 0]); pool:
-    runtime.kvblocks.init_paged_cache leaves (L, NB, bs, Hk, *), scanned
+    runtime.kvblocks.init_paged_cache leaves (L, NB, Hk, bs, *), scanned
     over layers exactly like the monolithic cache. Returns
     (logits (B, 1, V) f32 at each row's LAST valid span position,
     updated pool) — exactly the logits that sample the row's next token

@@ -7,8 +7,10 @@ batch, with the paged layout production servers use (vLLM /
 TensorRT-LLM style):
 
   * a physical pool of fixed-size blocks per layer —
-    `(L, num_blocks, block_size, Hk, Dh)` for K and V, plus per-(token,
-    head) scale planes when `cfg.kv_cache_bits == 8`;
+    `(L, num_blocks, Hk, block_size, Dh)` for K and V, plus per-(token,
+    head) scale planes when `cfg.kv_cache_bits == 8`. One (block, head)
+    tile is `(block_size, Dh)`, the last two dims, so the paged-attention
+    kernel DMAs whole tiles the TPU accepts;
   * a host-side `BlockPool` free-list allocator. Block 0 is reserved as
     the *trash block*: inactive batch rows write there and nothing ever
     reads it back, so the jitted step needs no control flow;
@@ -231,7 +233,7 @@ def copy_block(pool, src, dst):
     """Copy-on-write primitive: duplicate physical block `src` into `dst`
     across every pool leaf (codes and int8 scale planes alike). jit-safe
     with traced src/dst, and TP-safe — the copy moves along the block
-    axis 1 while `pool_pspecs` shards the KV-head axis 3, so each shard
+    axis 1 while `pool_pspecs` shards the KV-head axis 2, so each shard
     copies exactly its own head slice."""
     return {key: leaf.at[:, dst].set(leaf[:, src])
             for key, leaf in pool.items()}
@@ -239,17 +241,23 @@ def copy_block(pool, src, dst):
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None):
     """Physical pool arrays for every layer: {"k","v"} of shape
-    (L, num_blocks, block_size, Hk, Dh), plus {"ks","vs"} f32 scale planes
-    when cfg.kv_cache_bits == 8 (same int8 code + scale convention as
-    attention.init_kv_cache)."""
+    (L, num_blocks, Hk, block_size, Dh), plus {"ks","vs"} f32 scale planes
+    (L, num_blocks, Hk, block_size, 1) when cfg.kv_cache_bits == 8 (same
+    int8 code + scale convention as attention.init_kv_cache).
+
+    The paged-attention kernel reads one (block_size, Dh) tile per
+    (block, head). Those are the leaves' own last two dims, which the
+    TPU's tiling rules accept whole, so any block size compiles for every
+    KV dtype (tests/test_tpu_compile.py checks the int8 pool at the
+    serving block sizes)."""
     import jax.numpy as jnp
 
     check_paged_support(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     L, hk, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    shape = (L, num_blocks, block_size, hk, hd)
+    shape = (L, num_blocks, hk, block_size, hd)
     if getattr(cfg, "kv_cache_bits", 16) == 8:
-        sshape = (L, num_blocks, block_size, hk, 1)
+        sshape = (L, num_blocks, hk, block_size, 1)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "ks": jnp.ones(sshape, jnp.float32),
@@ -259,8 +267,8 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None):
 
 def pool_pspecs(cfg):
     """shard_map PartitionSpecs for the paged pool under tensor-parallel
-    serving: every pool leaf — (L, NB, bs, Hk, Dh) codes and the int8
-    (L, NB, bs, Hk, 1) scale planes — is sliced on the KV-head axis 3
+    serving: every pool leaf — (L, NB, Hk, bs, Dh) codes and the int8
+    (L, NB, Hk, bs, 1) scale planes — is sliced on the KV-head axis 2
     over the mesh "model" axis, so each shard owns the KV blocks for
     exactly the heads it computes. Block tables, step buffers, and all
     scheduler state stay host-side/replicated (P()); only the pool
@@ -268,7 +276,7 @@ def pool_pspecs(cfg):
     is bit-exact — shard r's codes and scales equal rows
     [r*Hk/tp, (r+1)*Hk/tp) of the single-device pool."""
     from jax.sharding import PartitionSpec as P
-    spec = P(None, None, None, "model", None)
+    spec = P(None, None, "model", None, None)
     keys = ("k", "v", "ks", "vs") if getattr(cfg, "kv_cache_bits", 16) == 8 \
         else ("k", "v")
     return {k: spec for k in keys}
@@ -282,13 +290,13 @@ def shard_pool(pool, tp: int, shard: int):
         raise ValueError(f"shard {shard} out of range for tp={tp}")
     out = {}
     for key, leaf in pool.items():
-        hk = leaf.shape[3]
+        hk = leaf.shape[2]
         if hk % tp:
             raise ValueError(
                 f"pool leaf {key!r} has {hk} KV heads, not divisible by "
                 f"tp={tp}")
         n = hk // tp
-        out[key] = leaf[:, :, :, shard * n:(shard + 1) * n]
+        out[key] = leaf[:, :, shard * n:(shard + 1) * n]
     return out
 
 

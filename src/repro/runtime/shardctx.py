@@ -93,21 +93,14 @@ def psum_tp(x):
 
 
 def tp_shard_map(fn, mesh, *, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions.
+    """jax.shard_map with varying-manual-axes checking off.
 
     The serving body's outputs are replicated by construction (every
     shard computes identical logits after the boundary psums), but the
-    static rep-checker cannot always prove that through the pool
+    static checker cannot always prove that through the pool
     scatter/gather, so it is disabled — the TP identity tests in
-    tests/test_tp_serving.py are the real check. jax renamed the flag
-    (check_rep -> check_vma) after 0.4.x; accept either."""
-    from jax.experimental.shard_map import shard_map
-
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    tests/test_tp_serving.py are the real check."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 

@@ -165,9 +165,8 @@ def test_engine_prompt_length_bucketing(smoke):
         a = eng.generate(prompts, SamplingParams(max_tokens=4))
         b = ref.generate(prompts, SamplingParams(max_tokens=4))
         np.testing.assert_array_equal(a.tokens, b.tokens)
-    if hasattr(eng._prefill, "_cache_size"):        # jax-version dependent
-        assert eng._prefill._cache_size() == 2      # one per bucket
-        assert ref._prefill._cache_size() == len(lens)
+    assert eng._prefill._cache_size() == 2          # one per bucket
+    assert ref._prefill._cache_size() == len(lens)
 
 
 def test_engine_accepts_ragged_requests(smoke):

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.itera import (
     LowRankQ, itera_decompose, reconstruction_error, svd_decompose,
@@ -97,8 +94,8 @@ def test_nops_and_storage():
     # decompose emits int8 carriers: resident cost is 8 bits/code until
     # the factors are packed (compress_params does this for W4 plans)
     assert lr.storage_bits() == (64 * 16 + 16 * 64) * 8 + 2 * 16 * 32
-    # hand-build the packed layout (these 16/64-wide axes are
-    # pad-inflating, so pack_weights itself refuses them — see
+    # hand-build the packed layout (these 16/64-wide axes are not whole
+    # pack tiles, so pack_weights itself refuses them — see
     # quant.packed_pad_ok): storage_bits counts the halved bytes either way
     import dataclasses
 

@@ -134,7 +134,7 @@ def test_qmm_packed_identical_to_carrier(m, k, n, wl):
     w = jax.random.normal(kw, (k, n), jnp.float32) / np.sqrt(k)
     wq = quantize(w, wl, axis=0)
     wp = pack_weights(wq)
-    # pack_weights only packs pad-ok axes; a pad-inflating N (e.g. 320,
+    # pack_weights only packs whole pack tiles; another N (e.g. 320,
     # 128) stays a carrier and wp is then wq itself — the identity below
     # still proves the no-op. The hand-built bad-axis case is covered by
     # test_qmm_forced_packed_bad_axis_demoted.
@@ -153,9 +153,9 @@ def test_qmm_packed_identical_to_carrier(m, k, n, wl):
 
 
 def test_qmm_forced_packed_bad_axis_demoted():
-    """A hand-built packed tensor on a pad-inflating axis (something
-    compress_params never produces) still computes bit-identically: the
-    dispatch demotes it to a carrier up front instead of fat-padding."""
+    """A hand-built packed tensor on an axis that is not whole pack
+    tiles (something compress_params never produces) still computes
+    bit-identically: the dispatch demotes it to a carrier up front."""
     import dataclasses
 
     from repro.core.quant import pack_int4
@@ -183,15 +183,15 @@ def test_lrmm_packed_identical_to_carrier(fused, wl):
     and unpack in-kernel, bit-identical to the carrier path — in the fused
     cascade AND the two-launch single-engine schedule."""
     key = jax.random.PRNGKey(11 + wl)
-    x = jax.random.normal(key, (48, 192), jnp.float32)
-    # R=192 and N=512 are both pad-ok axes, so a W4 decomposition packs
-    # both factors (pad-inflating axes would stay carriers — see
+    x = jax.random.normal(key, (48, 256), jnp.float32)
+    # R=256 and N=512 are whole pack tiles, so a W4 decomposition packs
+    # both factors (other widths stay carriers — see
     # test_lrmm_forced_packed_bad_axes_demoted)
-    w = jax.random.normal(key, (192, 512), jnp.float32) * 0.05
-    lr = svd_decompose(w, 192, wl)
+    w = jax.random.normal(key, (256, 512), jnp.float32) * 0.05
+    lr = svd_decompose(w, 256, wl)
     lrp = _pack_lr(lr)
     assert lrp.w1.packed == (wl == 4) and lrp.w2.packed == (wl == 4)
-    assert lrp.rank == 192 and lrp.w2.shape == (192, 512)
+    assert lrp.rank == 256 and lrp.w2.shape == (256, 512)
     y_carrier = ops.lrmm(x, lr, use_kernel=True, interpret=True, fused=fused)
     y_packed = ops.lrmm(x, lrp, use_kernel=True, interpret=True, fused=fused)
     np.testing.assert_array_equal(np.asarray(y_packed),
@@ -203,9 +203,9 @@ def test_lrmm_packed_identical_to_carrier(fused, wl):
 
 @pytest.mark.parametrize("fused", [True, False])
 def test_lrmm_forced_packed_bad_axes_demoted(fused):
-    """Hand-built packed factors on pad-inflating axes (R=96, N=320)
-    still compute bit-identically through both schedules — the dispatch
-    demotes them to carriers up front."""
+    """Hand-built packed factors on axes that are not whole pack tiles
+    (R=96, N=320) still compute bit-identically through both schedules —
+    the dispatch demotes them to carriers up front."""
     import dataclasses
 
     from repro.core.quant import pack_int4

@@ -1,5 +1,4 @@
-"""Property-based `BlockPool` invariants (hypothesis; falls back to the
-seeded-random shim in hypothesis_fallback when it is not installed).
+"""Property-based `BlockPool` invariants (hypothesis).
 
 Under ANY sequence of alloc/free operations the free-list allocator must
 uphold:
@@ -37,11 +36,7 @@ import dataclasses
 
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime import elastic
 from repro.runtime.kvblocks import (BlockPool, blocks_for_positions,
@@ -314,9 +309,9 @@ def test_shard_pool_partitions_heads_exactly():
             shards = [shard_pool(pool, tp, s) for s in range(tp)]
             for key, leaf in pool.items():
                 for s in range(tp):
-                    assert shards[s][key].shape[3] == hk // tp
+                    assert shards[s][key].shape[2] == hk // tp
                 glued = np.concatenate(
-                    [np.asarray(s[key]) for s in shards], axis=3)
+                    [np.asarray(s[key]) for s in shards], axis=2)
                 assert np.array_equal(glued, np.asarray(leaf)), key
         with pytest.raises(ValueError, match="shard"):
             shard_pool(pool, 2, 2)
@@ -445,7 +440,7 @@ def test_prefix_cache_refcounts_mirror_holders_exactly(case):
 
 
 def test_pool_pspecs_shard_heads_only():
-    """pool_pspecs slices exactly the KV-head axis (3) over "model" for
+    """pool_pspecs slices exactly the KV-head axis (2) over "model" for
     every pool leaf, and names the int8 scale planes iff the config
     carries int8 KV."""
     from jax.sharding import PartitionSpec as P
@@ -458,4 +453,4 @@ def test_pool_pspecs_shard_heads_only():
     specs8 = pool_pspecs(dataclasses.replace(cfg, kv_cache_bits=8))
     assert set(specs8) == {"k", "v", "ks", "vs"}
     for spec in list(specs.values()) + list(specs8.values()):
-        assert spec == P(None, None, None, "model", None)
+        assert spec == P(None, None, "model", None, None)

@@ -149,6 +149,38 @@ def test_packed_vs_carrier_serve_token_identical(smoke):
     assert outs[True][2] < outs[False][2]   # and the packed engine is smaller
 
 
+@pytest.mark.parametrize("kernel", ["qmm", "lrmm"])
+def test_packed_kernels_match_carrier_at_tile_widths(kernel):
+    """At opus-mt's tile-aligned widths (d=512, d_ff=2048, rank 256) the
+    packed W4 layout streams through both Pallas kernels (interpret mode)
+    bit-identically to the int8 carrier: the in-kernel half-tile unpack
+    reads exactly what core.quant.pack_int4 wrote."""
+    from repro.core.itera import svd_decompose
+    from repro.core.quant import pack_weights, quantize
+    from repro.kernels import ops
+
+    key = jax.random.PRNGKey(21)
+    x = jax.random.normal(key, (8, 512), jnp.float32)
+    w = jax.random.normal(key, (512, 2048), jnp.float32) * 0.05
+    if kernel == "qmm":
+        carrier = quantize(w, 4, axis=0)
+        packed = pack_weights(carrier)
+        assert packed.packed and packed.values.shape == (512, 1024)
+        run = ops.qmm
+    else:
+        carrier = svd_decompose(w, 256, 4)
+        packed = LowRankQ(pack_weights(carrier.w1), pack_weights(carrier.w2))
+        assert packed.w1.packed and packed.w2.packed
+        run = ops.lrmm
+    y_carrier = run(x, carrier, use_kernel=True, interpret=True)
+    y_packed = run(x, packed, use_kernel=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(y_packed),
+                                  np.asarray(y_carrier))
+    np.testing.assert_array_equal(
+        np.asarray(run(x, packed, use_kernel=False)),
+        np.asarray(run(x, carrier, use_kernel=False)))
+
+
 def test_act_wl_plan_changes_tokens(smoke):
     """act_wl is honored at runtime: an A4 engine's logits diverge from
     the A8 engine's (same weights, same prompts)."""
@@ -208,6 +240,41 @@ def test_ckpt_roundtrip_packed(tmp_path, smoke):
     for a, b in zip(jax.tree_util.tree_leaves(cp),
                     jax.tree_util.tree_leaves(restored_a4)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_ckpt_refuses_old_pack_layout(tmp_path, smoke):
+    """Packed bytes saved under the earlier interleaved nibble order have
+    the same shapes as today's, so the manifest's pack_layout is what
+    tells them apart: a packed node whose manifest entry lacks it, or
+    names another layout, is refused instead of restoring wrong weights.
+    Carrier nodes carry no pack layout and are unaffected."""
+    import json
+    import os
+
+    from repro.checkpoint import ckpt
+    from repro.core.quant import PACK_LAYOUT
+
+    _, params = smoke
+    plan = CompressionPlan.uniform(params, method="quant", weight_wl=4)
+    cp, _ = compress_params(params, plan)
+    ckpt.save(str(tmp_path), 3, cp)
+    path = os.path.join(str(tmp_path), "step_00000003", "manifest.json")
+    with open(path) as f:
+        manifest = json.load(f)
+    fmts = manifest["quant_formats"]
+    assert {f["pack_layout"] for f in fmts.values()} == {PACK_LAYOUT, None}
+    for old in (lambda f: f.pop("pack_layout"),
+                lambda f: f.update(pack_layout="int4-interleaved")):
+        for f in fmts.values():
+            if f["packed"]:
+                old(f)
+        with open(path, "w") as f:
+            json.dump(manifest, f)
+        with pytest.raises(ValueError, match="quant layout"):
+            ckpt.restore(str(tmp_path), cp)
+    carrier, _ = compress_params(params, plan.replace(pack=False))
+    ckpt.save(str(tmp_path), 4, carrier)
+    ckpt.restore(str(tmp_path), carrier, step=4)
 
 
 # ------------------------------------------------------- honest accounting --

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.quant import (
     fake_quant, pack_int4, pack_weights, packable, packed_pad_ok, qmax,
@@ -103,6 +100,29 @@ def test_pack_unpack_int4_exhaustive_range():
     np.testing.assert_array_equal(np.asarray(out), codes)
 
 
+@pytest.mark.parametrize("n", [256, 512, 768, 640])
+def test_pack_int4_tile_layout(n):
+    """The packed byte order the kernels read: within each 256-column
+    tile, byte j holds column j in its low nibble and column j + 128 in
+    its high nibble, so a kernel unpacks 128-byte half-tiles onto
+    128-lane boundaries. A narrower last tile (640 = 2 tiles + 128)
+    splits at its own half width. Round-trips exactly either way."""
+    rng = np.random.default_rng(n)
+    codes = rng.integers(-8, 8, size=(3, n)).astype(np.int8)
+    packed = np.asarray(pack_int4(jnp.asarray(codes))).astype(np.uint8)
+    assert packed.shape == (3, n // 2)
+    lo, hi = packed & 0x0F, packed >> 4
+    want_lo, want_hi = [], []
+    for t0 in range(0, n, 256):
+        half = min(256, n - t0) // 2
+        want_lo.append(codes[:, t0:t0 + half])
+        want_hi.append(codes[:, t0 + half:t0 + 2 * half])
+    np.testing.assert_array_equal(lo, np.concatenate(want_lo, 1) & 0x0F)
+    np.testing.assert_array_equal(hi, np.concatenate(want_hi, 1) & 0x0F)
+    np.testing.assert_array_equal(np.asarray(unpack_int4(jnp.asarray(
+        packed.astype(np.int8)))), codes)
+
+
 def test_pack_int4_rejects_odd_last_dim():
     with pytest.raises(ValueError, match="even last dim"):
         pack_int4(jnp.zeros((4, 5), jnp.int8))
@@ -110,20 +130,19 @@ def test_pack_int4_rejects_odd_last_dim():
 
 @given(matrix())
 def test_pack_weights_refuses_small_axes(w):
-    """The 2..24-wide hypothesis axes are all pad-inflating (a packed
-    half-width must pad to 256 lanes where the carrier pads to 128), so
-    pack_weights must refuse every one of them — packing would double
-    the kernels' padded work for zero byte savings."""
+    """The 2..24-wide hypothesis axes are narrower than one pack tile
+    (the kernels read whole 256-column tiles), so pack_weights must
+    refuse every one of them."""
     q = quantize(jnp.asarray(w), 4, axis=0)
     assert not packed_pad_ok(w.shape[-1])
     assert not packable(q) and pack_weights(q) is q
 
 
-@pytest.mark.parametrize("n", [192, 256, 512])
+@pytest.mark.parametrize("n", [256, 512, 768])
 def test_pack_weights_roundtrip(n):
     """pack_weights/unpack_weights is exact and dequant-invariant on any
-    W4 tensor whose last dim is even and pad-ok; odd / pad-inflating
-    dims and W6/W8 stay carriers."""
+    W4 tensor whose last dim is whole pack tiles; other dims and W6/W8
+    stay carriers."""
     w = jnp.asarray(np.random.default_rng(n).normal(size=(16, n)),
                     jnp.float32)
     q = quantize(w, 4, axis=0)

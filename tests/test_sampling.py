@@ -373,7 +373,11 @@ def test_speculative_stop_sequences_match_oracle(base):
     prompts = _prompts(cfg.vocab_size, seed=9)
     full = eng.serve(prompts, SamplingParams(max_tokens=8))
     stream = [np.asarray(o) for o in full.outputs]
-    eos = int(stream[0][1])
+    # stop on the token whose first appearance in any stream comes
+    # latest: rows then decode (and draft) before the stop can fire,
+    # where a token that opens most streams would stop every row first
+    eos = max((j, int(t)) for s in stream for j, t in enumerate(s)
+              if t not in s[:j])[1]
     sp = SamplingParams(max_tokens=8, eos_id=eos)
     res = eng.serve(prompts, sp)
     assert res.spec_rounds > 0

@@ -1,10 +1,7 @@
 """Tests for Sensitivity-based Rank Allocation (paper §IV)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.sra import sra_allocate, uniform_allocation
 

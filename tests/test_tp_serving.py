@@ -243,6 +243,32 @@ def test_tp_spec_rules_unit():
         tp_spec_for("layers/mlp/up", z((2, 64, 250)), 4)
 
 
+def test_tp_packed_slices_keep_whole_pack_tiles():
+    """A packed W4 leaf is column-sliced only where every shard keeps
+    whole 256-column pack tiles — then a shard's bytes are exactly the
+    packed form of its own columns. A slice that would cut tiles (each
+    shard's high nibbles would hold another shard's columns) is refused
+    with the path named; row-sliced K-sites never cut the packed axis."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.quant import pack_weights, quantize
+    from repro.launch.sharding import tp_param_specs
+
+    def packed(k, n):
+        q = pack_weights(quantize(jnp.ones((2, k, n)), 4, axis=1))
+        assert q.packed
+        return q
+
+    params = {"layers": {"mlp": {"up": packed(64, 1024),
+                                 "down": packed(1024, 256)}}}
+    specs = tp_param_specs(params, 4)["layers"]["mlp"]
+    assert specs["up"].values == P(None, None, "model")   # 256 cols/shard
+    assert specs["down"].values == P(None, "model", None)
+    with pytest.raises(ValueError, match=r"layers/attn/wq/values.*128"):
+        tp_param_specs({"layers": {"attn": {"wq": packed(64, 512)}}}, 4)
+
+
 def test_serving_mesh_needs_devices():
     """make_serving_mesh raises with the XLA_FLAGS recipe when the host
     has too few devices (this process sees exactly 1)."""

@@ -31,6 +31,7 @@ from tools.iteralint.framework import dotted_name, import_table
 
 JIT_TARGETS = {"jax.jit", "jax.pmap"}
 SHARD_TARGETS = {
+    "jax.shard_map",
     "jax.experimental.shard_map.shard_map",
     "jax.sharding.shard_map",
     "repro.runtime.shardctx.tp_shard_map",
