@@ -45,8 +45,9 @@ from repro.api.plan import CompressionPlan
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.core.compress import CompressionConfig, compress_params
+from repro.kernels import paged_attention as pa
 from repro.models import transformer as tfm
-from repro.runtime import kvblocks
+from repro.runtime import kvblocks, tracing
 from repro.runtime.scheduler import Request, Scheduler
 from repro.runtime.speculation import DraftSpec, SpeculationController
 
@@ -297,6 +298,20 @@ def _pow2_bucket(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
 
+def _step_counters(buf, ctx_col: int, block_size: int,
+                   table_width: int) -> dict:
+    """The `repro.serve.step` span's counters, from the step's packed
+    buffer (ctx_lens at column `ctx_col`, q_lens after it): positions
+    the step computes, KV blocks the attention kernel streams, and the
+    kernel's grid steps, the last two per layer and KV head (under
+    speculation, those of the verifying pass)."""
+    ctx, q = buf[:, ctx_col], buf[:, ctx_col + 1]
+    nb = kvblocks.valid_block_counts(ctx, q, block_size, table_width)
+    return {"valid_tokens": int(q.sum()),
+            "kv_blocks_valid": int(nb.sum()),
+            "kv_grid_blocks": pa.grid_blocks(buf.shape[0], table_width)}
+
+
 def _tree_nbytes(tree) -> int:
     """Bytes a pytree's arrays actually occupy (packed nibble arrays
     report their true halved size) — the single definition of measured
@@ -420,6 +435,7 @@ class InferenceEngine:
         # indices are traced scalars so one trace covers every copy, and
         # the op moves along the (unsharded) block axis so it is TP-inert.
         self._cow_copy = jax.jit(kvblocks.copy_block)
+        tracing.trace_collections()
 
     def _unified_fn(self, sample: bool, stop: bool):
         """The jitted fused serving step for one static (sample, stop)
@@ -428,6 +444,8 @@ class InferenceEngine:
         if fn is not None:
             return fn
         cfg = self.cfg
+        # the step is a named function: a profile names each compiled
+        # program after the function jitted (`jit_serving_step`)
         if self._tp:
             # shard_map the SAME fused step: each shard runs it with the
             # per-shard config (its slice of heads / hidden columns) over
@@ -448,21 +466,22 @@ class InferenceEngine:
             pspecs = shd.tp_param_specs(self.params, self._tp)
             pool_specs = kvblocks.pool_pspecs(cfg)
 
-            def tp_body(p, pool, bt, buf, prev, recent, stops):
+            def serving_step(p, pool, bt, buf, prev, recent, stops):
                 with shardctx.tp_axis("model"):
                     return tfm.serve_step(p, pool, bt, buf, prev, recent,
                                           stops, lcfg, sample=sample,
                                           stop=stop)
 
             fn = jax.jit(shardctx.tp_shard_map(
-                tp_body, self.mesh,
+                serving_step, self.mesh,
                 in_specs=(pspecs, pool_specs, P(), P(), P(), P(), P()),
                 out_specs=(P(), P(), P(), pool_specs)))
         else:
-            fn = jax.jit(
-                lambda p, pool, bt, buf, prev, recent, stops:
-                tfm.serve_step(p, pool, bt, buf, prev, recent, stops, cfg,
-                               sample=sample, stop=stop))
+            def serving_step(p, pool, bt, buf, prev, recent, stops):
+                return tfm.serve_step(p, pool, bt, buf, prev, recent, stops,
+                                      cfg, sample=sample, stop=stop)
+
+            fn = jax.jit(serving_step)
         self._unified_cache[(sample, stop)] = fn
         return fn
 
@@ -703,116 +722,122 @@ class InferenceEngine:
         pool is per-call); hit/COW/eviction counts land in the result.
         """
         sampling = sampling or SamplingParams()
-        ctl = self.speculation
-        if speculate is False:
-            ctl = None
-        elif speculate is True and ctl is None:
-            raise ValueError(
-                "speculate=True but the engine has no draft model — build "
-                "with speculate=DraftSpec(...) or a plan carrying .draft")
-        # resolve every per-request sampling/stop field against the
-        # call-level SamplingParams BEFORE submission: the scheduler and
-        # the packed-buffer build only ever see concrete values.
-        reqs: list[Request] = []
-        for i, r in enumerate(requests):
-            if not isinstance(r, Request):
-                r = Request(tokens=r)
-            repl: dict = {"rid": i}
-            if r.max_tokens is None:
-                repl["max_tokens"] = sampling.max_tokens
-            if r.temperature is None:
-                repl["temperature"] = sampling.temperature
-            if r.top_k is None:
-                repl["top_k"] = sampling.top_k
-            if r.top_p is None:
-                repl["top_p"] = sampling.top_p
-            if r.seed is None:
-                repl["seed"] = sampling.seed
-            if r.eos_id is None:
-                repl["eos_id"] = sampling.eos_id
-            if not r.stop:
-                repl["stop"] = sampling.stop
-            reqs.append(dataclasses.replace(r, **repl))
-        if not reqs:
-            raise ValueError("empty request batch")
-        kvblocks.check_paged_support(self.cfg)
-        # serve-call statics: which fused-step variant traces, and the
-        # stop-buffer geometry (ring width S, stop slots NS)
-        do_sample = any(r.temperature > 0.0 for r in reqs)
-        do_stop = any(r.eos_id is not None or r.stop for r in reqs)
-        n_stops = max([len(r.stop) for r in reqs] + [1])
-        stop_len = max([len(s) for r in reqs for s in r.stop] + [1])
+        with tracing.span("serve.setup"):
+            ctl = self.speculation
+            if speculate is False:
+                ctl = None
+            elif speculate is True and ctl is None:
+                raise ValueError(
+                    "speculate=True but the engine has no draft model — "
+                    "build with speculate=DraftSpec(...) or a plan carrying "
+                    ".draft")
+            # resolve every per-request sampling/stop field against the
+            # call-level SamplingParams BEFORE submission: the scheduler
+            # and the packed-buffer build only ever see concrete values.
+            reqs: list[Request] = []
+            for i, r in enumerate(requests):
+                if not isinstance(r, Request):
+                    r = Request(tokens=r)
+                repl: dict = {"rid": i}
+                if r.max_tokens is None:
+                    repl["max_tokens"] = sampling.max_tokens
+                if r.temperature is None:
+                    repl["temperature"] = sampling.temperature
+                if r.top_k is None:
+                    repl["top_k"] = sampling.top_k
+                if r.top_p is None:
+                    repl["top_p"] = sampling.top_p
+                if r.seed is None:
+                    repl["seed"] = sampling.seed
+                if r.eos_id is None:
+                    repl["eos_id"] = sampling.eos_id
+                if not r.stop:
+                    repl["stop"] = sampling.stop
+                reqs.append(dataclasses.replace(r, **repl))
+            if not reqs:
+                raise ValueError("empty request batch")
+            kvblocks.check_paged_support(self.cfg)
+            # serve-call statics: which fused-step variant traces, and the
+            # stop-buffer geometry (ring width S, stop slots NS)
+            do_sample = any(r.temperature > 0.0 for r in reqs)
+            do_stop = any(r.eos_id is not None or r.stop for r in reqs)
+            n_stops = max([len(r.stop) for r in reqs] + [1])
+            stop_len = max([len(s) for r in reqs for s in r.stop] + [1])
 
-        bs = block_size or self.block_size
-        cap = min(max_batch or self.max_batch, len(reqs))
-        budget = chunk_tokens or self.chunk_tokens
-        need = [kvblocks.blocks_needed(r.tokens.size, r.max_tokens, bs)
-                for r in reqs]
-        mb = max(max(need), 1)              # block-table width (static)
-        if num_blocks is None:
-            num_blocks = cap * mb + 1       # +1: reserved trash block
-        use_cache = self.prefix_cache if prefix_cache is None else prefix_cache
-        pool_alloc = kvblocks.BlockPool(num_blocks, bs)
-        sched = Scheduler(pool_alloc, cap, prefix_cache=use_cache,
-                          fingerprint=self._cache_fingerprint)
-        for r in reqs:
-            sched.submit(r)
+            bs = block_size or self.block_size
+            cap = min(max_batch or self.max_batch, len(reqs))
+            budget = chunk_tokens or self.chunk_tokens
+            need = [kvblocks.blocks_needed(r.tokens.size, r.max_tokens, bs)
+                    for r in reqs]
+            mb = max(max(need), 1)              # block-table width (static)
+            if num_blocks is None:
+                num_blocks = cap * mb + 1       # +1: reserved trash block
+            use_cache = (self.prefix_cache if prefix_cache is None
+                         else prefix_cache)
+            pool_alloc = kvblocks.BlockPool(num_blocks, bs)
+            sched = Scheduler(pool_alloc, cap, prefix_cache=use_cache,
+                              fingerprint=self._cache_fingerprint)
+            for r in reqs:
+                sched.submit(r)
 
-        pool = kvblocks.init_paged_cache(self.cfg, num_blocks, bs)
-        if self._tp:
-            from jax.sharding import NamedSharding
+            pool = kvblocks.init_paged_cache(self.cfg, num_blocks, bs)
+            if self._tp:
+                from jax.sharding import NamedSharding
 
-            pool = jax.device_put(
-                pool, {k: NamedSharding(self.mesh, s)
-                       for k, s in kvblocks.pool_pspecs(self.cfg).items()})
-        tables = np.zeros((cap, mb), np.int32)
-        out_vals: list[list[int]] = [[] for _ in reqs]
-        first_tok_t = [None] * len(reqs)
-        finish_t = [0.0] * len(reqs)
-        queue_t = [0.0] * len(reqs)
-        steps = prefill_chunks = prefill_tokens = mixed_steps = 0
-        drafted = accepted = spec_rounds = 0
-        spec_stopped = 0
+                pool = jax.device_put(
+                    pool, {k: NamedSharding(self.mesh, s) for k, s in
+                           kvblocks.pool_pspecs(self.cfg).items()})
+            tables = np.zeros((cap, mb), np.int32)
+            out_vals: list[list[int]] = [[] for _ in reqs]
+            first_tok_t = [None] * len(reqs)
+            finish_t = [0.0] * len(reqs)
+            queue_t = [0.0] * len(reqs)
+            steps = prefill_chunks = prefill_tokens = mixed_steps = 0
+            drafted = accepted = spec_rounds = 0
+            spec_stopped = 0
 
-        from repro.runtime import sampling as smp
-        from repro.runtime import shardctx
+            from repro.runtime import sampling as smp
+            from repro.runtime import shardctx
 
-        # TP serving must NOT install the GSPMD mesh: the step is a
-        # shard_map program over manual axes, where maybe_shard's
-        # with_sharding_constraint is meaningless (and errors).
-        ctx = (shardctx.use_mesh(self.mesh)
-               if self.mesh is not None and not self._tp
-               else contextlib.nullcontext())
+            # TP serving must NOT install the GSPMD mesh: the step is a
+            # shard_map program over manual axes, where maybe_shard's
+            # with_sharding_constraint is meaningless (and errors).
+            ctx = (shardctx.use_mesh(self.mesh)
+                   if self.mesh is not None and not self._tp
+                   else contextlib.nullcontext())
         t0 = time.time()
         # rids whose device stop criterion fired before max_tokens: the
         # pipeline learns (at most two steps late, at consume time) and
-        # discards the zombie steps' tokens; the loop top frees the row.
+        # discards the zombie steps' tokens; that step frees the row.
         stopped: set[int] = set()
 
         def consume(emits, toks_dev, fin_dev):
             """Read back one step's sampled tokens + finished mask
             (blocks until the device finishes that step) and credit
             them to requests."""
-            vals = np.asarray(toks_dev)
-            fins = None if fin_dev is None else np.asarray(fin_dev)
-            now = time.time()
-            for rid, r in emits:
-                if rid in stopped:
-                    continue    # zombie tokens dispatched past the stop
-                out_vals[rid].append(int(vals[r, 0]))
-                if first_tok_t[rid] is None:
-                    first_tok_t[rid] = now
-                done = len(out_vals[rid]) >= reqs[rid].max_tokens
-                if fins is not None and fins[r]:
-                    if not done:            # eos / stop sequence fired
-                        stopped.add(rid)    # before the token budget ran
-                    done = True
-                if done:
-                    finish_t[rid] = now
-                if on_token is not None:
-                    on_token(TokenEvent(rid=rid, token=out_vals[rid][-1],
-                                        index=len(out_vals[rid]) - 1,
-                                        time=now, final=done))
+            with tracing.span("serve.consume"):
+                with tracing.span("serve.readback"):
+                    vals = np.asarray(toks_dev)
+                    fins = None if fin_dev is None else np.asarray(fin_dev)
+                now = time.time()
+                for rid, r in emits:
+                    if rid in stopped:
+                        continue    # zombie tokens dispatched past the stop
+                    out_vals[rid].append(int(vals[r, 0]))
+                    if first_tok_t[rid] is None:
+                        first_tok_t[rid] = now
+                    done = len(out_vals[rid]) >= reqs[rid].max_tokens
+                    if fins is not None and fins[r]:
+                        if not done:            # eos / stop sequence fired
+                            stopped.add(rid)    # before the token budget ran
+                        done = True
+                    if done:
+                        finish_t[rid] = now
+                    if on_token is not None:
+                        on_token(TokenEvent(rid=rid,
+                                            token=out_vals[rid][-1],
+                                            index=len(out_vals[rid]) - 1,
+                                            time=now, final=done))
 
         with ctx:
             if ctl is not None:
@@ -834,112 +859,130 @@ class InferenceEngine:
             prev_toks = jnp.zeros((cap, 1), jnp.int32)
             recent = jnp.zeros((cap, stop_len), jnp.int32)
             while not sched_done and sched.has_work():
-                # rows whose stop fired (discovered at consume): retire
-                # them before scheduling so the row + blocks free now
-                if stopped:
-                    for seq in list(sched.rows):
-                        if seq is not None and seq.req.rid in stopped:
-                            sched.finish(seq)
+                with tracing.span("serve.step") as step_span:
+                    with tracing.span("serve.schedule"):
+                        plan = sched.schedule(budget)
+                        for r in plan.preempted:  # victim rows: table to
+                            tables[r] = 0         # trash (before any
+                            tables_dev = None     # admission reusing it)
+                        for seq in plan.admitted:
                             tables[seq.row] = 0
+                            tables[seq.row, :len(seq.block_ids)] = \
+                                seq.block_ids
                             tables_dev = None
+                            queue_t[seq.req.rid] = time.time() - t0
+                            if do_stop:
+                                stop_buf[seq.row] = smp.pack_stop_seqs(
+                                    seq.req.stop, n_stops, stop_len)
+                                stops_dev = None
+                            if seq.cow_dst is not None:
+                                # fully-cached prompt: materialize a
+                                # private copy of the last matched block
+                                # before this step's span write
+                                # recomputes its final position
+                                pool = self._cow_copy(
+                                    pool, jnp.int32(seq.cow_src),
+                                    jnp.int32(seq.cow_dst))
+                                sched.release_cow(seq)
+                        if not plan.prefill and not plan.decode:
+                            raise RuntimeError(
+                                "scheduler returned an empty step with "
+                                "work pending — admission deadlock")
+                    # ---- build the (cap, W + meta) span batch ------------
+                    # one fresh packed buffer per step: span tokens, the
+                    # (ctx, q_len, use_prev) scheduling columns, then the
+                    # packed per-row sampling/stop metadata — still ONE
+                    # upload. Handed to the jitted step as numpy — never
+                    # mutated after dispatch, so jax's zero-copy aliasing
+                    # of host buffers is safe here.
+                    with tracing.span("serve.build"):
+                        w = _pow2_bucket(plan.max_span)
+                        m = smp.SAMP_COLS
+                        buf = np.zeros((cap, w + 3 + m), np.int32)
+                        for r, width in plan.prefill.items():
+                            seq = sched.rows[r]
+                            lo = seq.prefilled
+                            buf[r, :width] = seq.req.tokens[lo:lo + width]
+                            buf[r, -(m + 3)] = lo
+                            buf[r, -(m + 2)] = width
+                        for r in plan.decode:
+                            seq = sched.rows[r]
+                            # the input token is the one sampled last
+                            # step; it is still on device (prev_toks),
+                            # spliced in by the step. pool holds prompt +
+                            # all but that newest token.
+                            buf[r, -(m + 3)] = (seq.prompt_len
+                                                + seq.n_emitted - 1)
+                            buf[r, -(m + 2)] = 1
+                            buf[r, -(m + 1)] = 1
+                        for r in list(plan.prefill) + plan.decode:
+                            seq = sched.rows[r]
+                            smp.write_row_meta(buf, r, seq.req,
+                                               seq.n_emitted)
+                    if tracing.active():
+                        step_span.set_metadata(**_step_counters(
+                            buf, -(m + 3), bs, mb))
+                    # ---- ONE fused dispatch for the prefill/decode mix ---
+                    with tracing.span("serve.dispatch"):
+                        if tables_dev is None:
+                            # a private copy: `tables` is mutated by later
+                            # admissions/evictions while earlier
+                            # dispatched steps may still be reading the
+                            # (possibly aliased) upload
+                            tables_dev = tables.copy()
+                        if do_stop and stops_dev is None:
+                            stops_dev = stop_buf.copy()
+                        toks_dev, fin_dev, recent, pool = step_fn(
+                            self.params, pool, tables_dev, buf, prev_toks,
+                            recent, stops_dev if do_stop else no_stops)
+                    steps += 1
+                    prefill_chunks += len(plan.prefill)
+                    prefill_tokens += sum(plan.prefill.values())
+                    mixed_steps += plan.is_mixed
+                    prev_toks = toks_dev
+                    # ---- count-based bookkeeping at dispatch time --------
+                    # (scheduling never waits on token values — eviction
+                    # and admission run ahead of the device;
+                    # value-dependent stops arrive via the pipelined
+                    # finished mask above)
+                    emits = []
+                    for r, width in plan.prefill.items():
+                        # advance + register newly completed full prompt
+                        # blocks into the content index (dispatch order =
+                        # device order, so later readers see the writes)
+                        sched.advance_prefill(sched.rows[r], width)
+                    for r in list(plan.prefill) + plan.decode:
+                        seq = sched.rows[r]
+                        if not seq.prefill_done:
+                            continue            # mid-prompt: logits unused
+                        seq.n_emitted += 1
+                        emits.append((seq.req.rid, r))
+                        if seq.done:
+                            sched.finish(seq)
+                            tables[r] = 0
+                            tables_dev = None
+                    # ---- consume an older step while this one runs -------
+                    # (two steps of lookahead keep the device queue busy
+                    # through the host's scheduling + readback work)
+                    inflight.append((emits, toks_dev,
+                                     fin_dev if do_stop else None))
+                    if len(inflight) > 2:
+                        consume(*inflight.popleft())
+                    if stopped:
+                        # rows whose stop fired (discovered at consume):
+                        # retire them before the next schedule so the
+                        # row + blocks free now
+                        with tracing.span("serve.schedule"):
+                            for seq in list(sched.rows):
+                                if seq is not None and seq.req.rid in stopped:
+                                    sched.finish(seq)
+                                    tables[seq.row] = 0
+                                    tables_dev = None
                     if not sched.has_work():
-                        break
-                plan = sched.schedule(budget)
-                for r in plan.preempted:    # victim rows: table to trash
-                    tables[r] = 0           # (before any admission that
-                    tables_dev = None       # reuses the row below)
-                for seq in plan.admitted:
-                    tables[seq.row] = 0
-                    tables[seq.row, :len(seq.block_ids)] = seq.block_ids
-                    tables_dev = None
-                    queue_t[seq.req.rid] = time.time() - t0
-                    if do_stop:
-                        stop_buf[seq.row] = smp.pack_stop_seqs(
-                            seq.req.stop, n_stops, stop_len)
-                        stops_dev = None
-                    if seq.cow_dst is not None:
-                        # fully-cached prompt: materialize a private copy
-                        # of the last matched block before this step's
-                        # span write recomputes its final position
-                        pool = self._cow_copy(pool, jnp.int32(seq.cow_src),
-                                              jnp.int32(seq.cow_dst))
-                        sched.release_cow(seq)
-                if not plan.prefill and not plan.decode:
-                    raise RuntimeError(
-                        "scheduler returned an empty step with work "
-                        "pending — admission deadlock")
-                # ---- build the (cap, W + meta) span batch ----------------
-                # one fresh packed buffer per step: span tokens, the
-                # (ctx, q_len, use_prev) scheduling columns, then the
-                # packed per-row sampling/stop metadata — still ONE
-                # upload. Handed to the jitted step as numpy — never
-                # mutated after dispatch, so jax's zero-copy aliasing of
-                # host buffers is safe here.
-                w = _pow2_bucket(plan.max_span)
-                m = smp.SAMP_COLS
-                buf = np.zeros((cap, w + 3 + m), np.int32)
-                for r, width in plan.prefill.items():
-                    seq = sched.rows[r]
-                    lo = seq.prefilled
-                    buf[r, :width] = seq.req.tokens[lo:lo + width]
-                    buf[r, -(m + 3)] = lo
-                    buf[r, -(m + 2)] = width
-                for r in plan.decode:
-                    seq = sched.rows[r]
-                    # the input token is the one sampled last step; it is
-                    # still on device (prev_toks), spliced in by the step.
-                    # pool holds prompt + all but that newest token.
-                    buf[r, -(m + 3)] = seq.prompt_len + seq.n_emitted - 1
-                    buf[r, -(m + 2)] = 1
-                    buf[r, -(m + 1)] = 1
-                for r in list(plan.prefill) + plan.decode:
-                    seq = sched.rows[r]
-                    smp.write_row_meta(buf, r, seq.req, seq.n_emitted)
-                # ---- ONE fused dispatch for the prefill/decode mix -------
-                if tables_dev is None:
-                    # a private copy: `tables` is mutated by later
-                    # admissions/evictions while earlier dispatched steps
-                    # may still be reading the (possibly aliased) upload
-                    tables_dev = tables.copy()
-                if do_stop and stops_dev is None:
-                    stops_dev = stop_buf.copy()
-                toks_dev, fin_dev, recent, pool = step_fn(
-                    self.params, pool, tables_dev, buf, prev_toks,
-                    recent, stops_dev if do_stop else no_stops)
-                steps += 1
-                prefill_chunks += len(plan.prefill)
-                prefill_tokens += sum(plan.prefill.values())
-                mixed_steps += plan.is_mixed
-                prev_toks = toks_dev
-                # ---- count-based bookkeeping at dispatch time ------------
-                # (scheduling never waits on token values — eviction and
-                # admission run ahead of the device; value-dependent
-                # stops arrive via the pipelined finished mask above)
-                emits = []
-                for r, width in plan.prefill.items():
-                    # advance + register newly completed full prompt
-                    # blocks into the content index (dispatch order =
-                    # device order, so later readers see the writes)
-                    sched.advance_prefill(sched.rows[r], width)
-                for r in list(plan.prefill) + plan.decode:
-                    seq = sched.rows[r]
-                    if not seq.prefill_done:
-                        continue            # mid-prompt: logits unused
-                    seq.n_emitted += 1
-                    emits.append((seq.req.rid, r))
-                    if seq.done:
-                        sched.finish(seq)
-                        tables[r] = 0
-                        tables_dev = None
-                # ---- consume an older step while this one runs -----------
-                # (two steps of lookahead keep the device queue busy
-                # through the host's scheduling + readback work)
-                inflight.append((emits, toks_dev,
-                                 fin_dev if do_stop else None))
-                if len(inflight) > 2:
-                    consume(*inflight.popleft())
-            while inflight:
-                consume(*inflight.popleft())
+                        # the last dispatch: read back what is in flight
+                        with tracing.span("serve.drain"):
+                            while inflight:
+                                consume(*inflight.popleft())
             # stops discovered in the final drain: the rows already
             # finished by count, but late-stopped outputs stay truncated
             if not sched_done:
@@ -1011,115 +1054,130 @@ class InferenceEngine:
         tables_dev = None
         prev_toks = jnp.zeros((cap, 1), jnp.int32)
         while sched.has_work():
-            plan = sched.schedule(budget, spec_k=ctl.spec.k)
-            for r in plan.preempted:
-                tables[r] = 0
-                tables_dev = None
-            for seq in plan.admitted:
-                tables[seq.row] = 0
-                tables[seq.row, :len(seq.block_ids)] = seq.block_ids
-                tables_dev = None
-                queue_t[seq.req.rid] = time.time() - t0
-                if seq.cow_dst is not None:
-                    pool = self._cow_copy(pool, jnp.int32(seq.cow_src),
-                                          jnp.int32(seq.cow_dst))
-                    sched.release_cow(seq)
-            # draft-block reservations can grow a row's table mid-flight
-            # (only when admission could not pre-reserve the worst case)
-            for r in plan.spec:
-                seq = sched.rows[r]
-                if seq.draft_blocks:
-                    tables[r, :len(seq.block_ids)] = seq.block_ids
-                    tables_dev = None
-            if not plan.prefill and not plan.decode:
-                raise RuntimeError(
-                    "scheduler returned an empty step with work "
-                    "pending — admission deadlock")
-            # ---- (cap, W + meta) span batch; meta gains spec_lens -------
-            k_step = ctl.spec.k if plan.spec else 0
-            w = _pow2_bucket(max(plan.max_span, k_step + 1))
-            buf = np.zeros((cap, w + 4 + m), np.int32)
-            for r, width in plan.prefill.items():
-                seq = sched.rows[r]
-                lo = seq.prefilled
-                buf[r, :width] = seq.req.tokens[lo:lo + width]
-                buf[r, -(m + 4)] = lo
-                buf[r, -(m + 3)] = width
-            for r in plan.decode:
-                seq = sched.rows[r]
-                kr = plan.spec.get(r, 0)
-                # span: [prev (device-spliced), kr draft slots]
-                buf[r, -(m + 4)] = seq.prompt_len + seq.n_emitted - 1
-                buf[r, -(m + 3)] = 1 + kr
-                buf[r, -(m + 2)] = 1
-                buf[r, -(m + 1)] = kr
-            for r in list(plan.prefill) + plan.decode:
-                seq = sched.rows[r]
-                smp.write_row_meta(buf, r, seq.req, seq.n_emitted)
-            if tables_dev is None:
-                tables_dev = tables.copy()
-            full_toks, n_acc, prev_toks, pool = ctl.step_fn(
-                k_step, do_sample)(
-                self.params, ctl.draft_params, pool, tables_dev, buf,
-                prev_toks)
-            steps += 1
-            spec_rounds += bool(plan.spec)
-            prefill_chunks += len(plan.prefill)
-            prefill_tokens += sum(plan.prefill.values())
-            mixed_steps += plan.is_mixed
-            # acceptance decides how far each row advanced: read back now
-            fv = np.asarray(full_toks)
-            na = np.asarray(n_acc)
-            now = time.time()
-            for r, width in plan.prefill.items():
-                sched.advance_prefill(sched.rows[r], width)
-            for r in list(plan.prefill) + plan.decode:
-                seq = sched.rows[r]
-                if not seq.prefill_done:
-                    continue        # mid-prompt: logits unused
-                if r in plan.prefill:
-                    # prompt finished this step: emit the last-valid-
-                    # position token (appended verify column k_step + 1)
-                    toks = fv[r, k_step + 1:k_step + 2]
-                else:
-                    # decode: accepted draft prefix + the full model's
-                    # own token at the first divergence (or the bonus)
-                    toks = fv[r, :int(na[r]) + 1]
-                rid = seq.req.rid
-                prev_len = len(out_vals[rid])
-                out_vals[rid].extend(int(t) for t in toks)
-                if first_tok_t[rid] is None:
-                    first_tok_t[rid] = now
-                seq.n_emitted += len(toks)
-                kr = plan.spec.get(r, 0)
-                if kr:
-                    drafted += kr
-                    accepted += len(toks) - 1
-                    if sched.commit_speculation(seq):
-                        # rollback released tail blocks: rewind the table
+            with tracing.span("serve.step") as step_span:
+                with tracing.span("serve.schedule"):
+                    plan = sched.schedule(budget, spec_k=ctl.spec.k)
+                    for r in plan.preempted:
                         tables[r] = 0
-                        tables[r, :len(seq.block_ids)] = seq.block_ids
                         tables_dev = None
-                # host-side stop check (shared oracle; tokens already
-                # read back). Inclusive semantics: keep through the
-                # matching token, drop anything verified past it.
-                keep = smp.match_stop_host(out_vals[rid], seq.req.eos_id,
-                                           seq.req.stop, seq.max_tokens)
-                if keep is not None:
-                    del out_vals[rid][keep:]
-                if on_token is not None:
-                    for j in range(prev_len, len(out_vals[rid])):
-                        on_token(TokenEvent(
-                            rid=rid, token=out_vals[rid][j], index=j,
-                            time=now,
-                            final=(keep is not None
-                                   and j == len(out_vals[rid]) - 1)))
-                if keep is not None:
-                    stopped_early += len(out_vals[rid]) < seq.max_tokens
-                    finish_t[rid] = now
-                    sched.finish(seq)
-                    tables[r] = 0
-                    tables_dev = None
+                    for seq in plan.admitted:
+                        tables[seq.row] = 0
+                        tables[seq.row, :len(seq.block_ids)] = seq.block_ids
+                        tables_dev = None
+                        queue_t[seq.req.rid] = time.time() - t0
+                        if seq.cow_dst is not None:
+                            pool = self._cow_copy(pool,
+                                                  jnp.int32(seq.cow_src),
+                                                  jnp.int32(seq.cow_dst))
+                            sched.release_cow(seq)
+                    # draft-block reservations can grow a row's table
+                    # mid-flight (only when admission could not
+                    # pre-reserve the worst case)
+                    for r in plan.spec:
+                        seq = sched.rows[r]
+                        if seq.draft_blocks:
+                            tables[r, :len(seq.block_ids)] = seq.block_ids
+                            tables_dev = None
+                    if not plan.prefill and not plan.decode:
+                        raise RuntimeError(
+                            "scheduler returned an empty step with work "
+                            "pending — admission deadlock")
+                # ---- (cap, W + meta) span batch; meta gains spec_lens ---
+                with tracing.span("serve.build"):
+                    k_step = ctl.spec.k if plan.spec else 0
+                    w = _pow2_bucket(max(plan.max_span, k_step + 1))
+                    buf = np.zeros((cap, w + 4 + m), np.int32)
+                    for r, width in plan.prefill.items():
+                        seq = sched.rows[r]
+                        lo = seq.prefilled
+                        buf[r, :width] = seq.req.tokens[lo:lo + width]
+                        buf[r, -(m + 4)] = lo
+                        buf[r, -(m + 3)] = width
+                    for r in plan.decode:
+                        seq = sched.rows[r]
+                        kr = plan.spec.get(r, 0)
+                        # span: [prev (device-spliced), kr draft slots]
+                        buf[r, -(m + 4)] = seq.prompt_len + seq.n_emitted - 1
+                        buf[r, -(m + 3)] = 1 + kr
+                        buf[r, -(m + 2)] = 1
+                        buf[r, -(m + 1)] = kr
+                    for r in list(plan.prefill) + plan.decode:
+                        seq = sched.rows[r]
+                        smp.write_row_meta(buf, r, seq.req, seq.n_emitted)
+                if tracing.active():
+                    step_span.set_metadata(**_step_counters(
+                        buf, -(m + 4), sched.pool.block_size,
+                        tables.shape[1]))
+                with tracing.span("serve.dispatch"):
+                    if tables_dev is None:
+                        tables_dev = tables.copy()
+                    full_toks, n_acc, prev_toks, pool = ctl.step_fn(
+                        k_step, do_sample)(
+                        self.params, ctl.draft_params, pool, tables_dev,
+                        buf, prev_toks)
+                steps += 1
+                spec_rounds += bool(plan.spec)
+                prefill_chunks += len(plan.prefill)
+                prefill_tokens += sum(plan.prefill.values())
+                mixed_steps += plan.is_mixed
+                # acceptance decides how far each row advanced: read back
+                # now
+                with tracing.span("serve.readback"):
+                    fv = np.asarray(full_toks)
+                    na = np.asarray(n_acc)
+                now = time.time()
+                for r, width in plan.prefill.items():
+                    sched.advance_prefill(sched.rows[r], width)
+                for r in list(plan.prefill) + plan.decode:
+                    seq = sched.rows[r]
+                    if not seq.prefill_done:
+                        continue        # mid-prompt: logits unused
+                    if r in plan.prefill:
+                        # prompt finished this step: emit the last-valid-
+                        # position token (appended verify column
+                        # k_step + 1)
+                        toks = fv[r, k_step + 1:k_step + 2]
+                    else:
+                        # decode: accepted draft prefix + the full model's
+                        # own token at the first divergence (or the bonus)
+                        toks = fv[r, :int(na[r]) + 1]
+                    rid = seq.req.rid
+                    prev_len = len(out_vals[rid])
+                    out_vals[rid].extend(int(t) for t in toks)
+                    if first_tok_t[rid] is None:
+                        first_tok_t[rid] = now
+                    seq.n_emitted += len(toks)
+                    kr = plan.spec.get(r, 0)
+                    if kr:
+                        drafted += kr
+                        accepted += len(toks) - 1
+                        if sched.commit_speculation(seq):
+                            # rollback released tail blocks: rewind the
+                            # table
+                            tables[r] = 0
+                            tables[r, :len(seq.block_ids)] = seq.block_ids
+                            tables_dev = None
+                    # host-side stop check (shared oracle; tokens already
+                    # read back). Inclusive semantics: keep through the
+                    # matching token, drop anything verified past it.
+                    keep = smp.match_stop_host(out_vals[rid],
+                                               seq.req.eos_id,
+                                               seq.req.stop, seq.max_tokens)
+                    if keep is not None:
+                        del out_vals[rid][keep:]
+                    if on_token is not None:
+                        for j in range(prev_len, len(out_vals[rid])):
+                            on_token(TokenEvent(
+                                rid=rid, token=out_vals[rid][j], index=j,
+                                time=now,
+                                final=(keep is not None
+                                       and j == len(out_vals[rid]) - 1)))
+                    if keep is not None:
+                        stopped_early += len(out_vals[rid]) < seq.max_tokens
+                        finish_t[rid] = now
+                        sched.finish(seq)
+                        tables[r] = 0
+                        tables_dev = None
         return (steps, prefill_chunks, prefill_tokens, mixed_steps,
                 drafted, accepted, spec_rounds, stopped_early)
 

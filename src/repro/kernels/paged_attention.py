@@ -121,6 +121,19 @@ def _kernel(nb_ref, bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
                        jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
+def kernel_grid(rows: int, kv_heads: int, table_width: int) -> tuple:
+    """The kernel's grid: one program per (row, KV head, table slot)."""
+    return (rows, kv_heads, table_width)
+
+
+def grid_blocks(rows: int, table_width: int) -> int:
+    """Grid steps the kernel runs per layer and KV head, each one block
+    slot of a row's table whether or not it holds valid context (host
+    side: the serving loop's `kv_grid_blocks` counter)."""
+    r, _, w = kernel_grid(rows, 1, table_width)
+    return r * w
+
+
 @functools.partial(
     jax.jit, static_argnames=("logit_softcap", "interpret"))
 def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
@@ -189,7 +202,7 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # nb, block_table, ctx_lens
-        grid=(b, hk, mb),
+        grid=kernel_grid(b, hk, mb),
         in_specs=[pl.BlockSpec((1, 1, wg, dh), q_map)] + kv_specs,
         out_specs=pl.BlockSpec((1, 1, wg, dh), q_map),
         scratch_shapes=[

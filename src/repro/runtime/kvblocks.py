@@ -46,8 +46,8 @@ from collections import OrderedDict
 import numpy as np
 
 # jax.numpy is imported lazily inside the device-facing functions
-# (init_paged_cache, valid_block_counts, span_slots): the allocator /
-# digest half of this module is on the scheduler's host path, and
+# (init_paged_cache, span_slots): the allocator / digest half of this
+# module is on the scheduler's host path, and
 # `from repro.runtime.kvblocks import BlockPool` must not initialize a
 # device runtime. Function-local imports are trace-safe — they run at
 # trace time, not per step.
@@ -308,15 +308,14 @@ def valid_block_counts(ctx_lens, q_lens, block_size, max_blocks):
     `ctx_lens[r] + q_lens[r]` valid positions = the first
     ceil((ctx + q) / block_size) table entries; everything past that is
     trash-block-0 padding the kernel must never fetch. Idle rows
-    (q_lens == 0) count zero — the kernel skips them entirely. jit-safe
-    (pure index math); clamped to the table width for caller-supplied
-    out-of-range metadata."""
-    import jax.numpy as jnp
-
+    (q_lens == 0) count zero — the kernel skips them entirely. Pure index
+    math on numpy or jax arrays alike: the serving step traces it for the
+    kernel's grid, and the serve loop's step counters run it on the host
+    copy of the same columns. Clamped to the table width for
+    caller-supplied out-of-range metadata."""
     total = ctx_lens + q_lens
-    nb = (total + block_size - 1) // block_size
-    nb = jnp.where(q_lens > 0, nb, 0)
-    return jnp.clip(nb, 0, max_blocks).astype(jnp.int32)
+    nb = (total + block_size - 1) // block_size * (q_lens > 0)
+    return nb.clip(0, max_blocks).astype(np.int32)
 
 
 def span_slots(block_table, ctx_lens, q_lens, width, block_size):

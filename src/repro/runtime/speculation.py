@@ -324,21 +324,25 @@ class SpeculationController:
 
                 pool_specs = kvblocks.pool_pspecs(self.cfg)
 
-                def tp_body(p, dp, pool, bt, buf, prev, _k=k, _s=sample):
+                def body(p, dp, pool, bt, buf, prev, _k=k, _s=sample):
                     with shardctx.tp_axis("model"):
                         return speculative_step(p, dp, pool, bt, buf, prev,
                                                 self._local_cfg, _k,
                                                 sample=_s)
 
+                # a profile names the compiled program after this
+                body.__name__ = "speculative_step"
                 fn = jax.jit(shardctx.tp_shard_map(
-                    tp_body, self.mesh,
+                    body, self.mesh,
                     in_specs=(self._pspecs, self._dspecs, pool_specs,
                               P(), P(), P()),
                     out_specs=(P(), P(), P(), pool_specs)))
             else:
-                fn = jax.jit(
-                    lambda p, dp, pool, bt, buf, prev, _k=k, _s=sample:
-                    speculative_step(p, dp, pool, bt, buf, prev, self.cfg,
-                                     _k, sample=_s))
+                def body(p, dp, pool, bt, buf, prev, _k=k, _s=sample):
+                    return speculative_step(p, dp, pool, bt, buf, prev,
+                                            self.cfg, _k, sample=_s)
+
+                body.__name__ = "speculative_step"
+                fn = jax.jit(body)
             self._steps[(k, sample)] = fn
         return fn
