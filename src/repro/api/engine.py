@@ -303,13 +303,14 @@ def _step_counters(buf, ctx_col: int, block_size: int,
     """The `repro.serve.step` span's counters, from the step's packed
     buffer (ctx_lens at column `ctx_col`, q_lens after it): positions
     the step computes, KV blocks the attention kernel streams, and the
-    kernel's grid steps, the last two per layer and KV head (under
-    speculation, those of the verifying pass)."""
+    block slots its chunks cover, the last two per layer and KV head
+    (under speculation, those of the verifying pass)."""
     ctx, q = buf[:, ctx_col], buf[:, ctx_col + 1]
     nb = kvblocks.valid_block_counts(ctx, q, block_size, table_width)
     return {"valid_tokens": int(q.sum()),
             "kv_blocks_valid": int(nb.sum()),
-            "kv_grid_blocks": pa.grid_blocks(buf.shape[0], table_width)}
+            "kv_grid_blocks": pa.grid_blocks(
+                nb, pa.chunk_blocks(block_size, table_width))}
 
 
 def _tree_nbytes(tree) -> int:

@@ -10,22 +10,26 @@ O(max-context) HBM traffic and a full dense materialization regardless of
 how much context each row actually holds — and with int8 KV it dequantizes
 that whole window in jnp before the dot. This kernel instead:
 
-  * runs a `(B, Hk, MB)` grid — one program per (row, kv-head, table slot)
-    — with the block table, `ctx_lens`, `q_lens`, and the per-row
-    valid-block counts (`runtime.kvblocks.valid_block_counts`) scalar-
-    prefetched into SMEM, so the BlockSpec index maps can chase the table;
-  * walks the block table and fetches ONLY blocks that hold valid context:
-    grid step j DMAs physical block `block_table[r, min(j, nb[r]-1)]`, so
-    every step past a row's valid count re-addresses the block already
-    resident in VMEM — the Pallas pipeline skips the re-fetch. Trash-
-    block-0 padding entries past a row's valid count are never addressed
-    (pads sit at `j >= nb`); idle rows (`q_lens == 0`, `nb == 0`) clamp
-    onto `block_table[r, 0]` — the trash block — so they fetch that one
-    block and compute nothing (`stream_hbm_bytes` charges exactly that);
-  * computes online softmax over (W-span queries x block keys) with the
-    in-span causal mask `slot <= ctx_lens[r] + i` fused into the score
-    tile (key position `j*bs + col` vs query position `ctx + row // G`),
-    in f32 running (m, l, acc) VMEM scratch;
+  * runs a `(B, Hk // ht)` grid — one program per (row, group of `ht` KV
+    heads) — with the block table, `ctx_lens` and the per-row valid-block
+    counts `nb` (`runtime.kvblocks.valid_block_counts`) scalar-prefetched
+    into SMEM. The number of programs no longer grows with the table
+    width, only the work inside a program grows with the row's context;
+  * walks the row's block table in chunks of `P` entries inside the
+    program (`lax.fori_loop` over ceil(nb / P) chunks): the pool leaves
+    stay in HBM (`memory_space=pl.ANY`) and the program DMAs
+    `pool[block_table[r, j], h0:h0+ht]` itself, one copy per valid entry
+    `j < nb[r]`, into a two-slot VMEM buffer — chunk c+1's copies start
+    before chunk c is computed. Entries at `j >= nb[r]` (the trash-block-0
+    padding) are never copied; idle rows (`q_lens == 0`, `nb == 0`) copy
+    nothing, run no chunk, and write zeros;
+  * computes online softmax over (W-span queries x P*bs chunk keys), one
+    dot batched over the program's heads per chunk, with the in-span
+    causal mask `slot <= ctx_lens[r] + i` fused into the score tile (key
+    position `c*P*bs + col` vs query position `ctx + row // G`); the
+    unfilled slots of a partial last chunk hold stale VMEM, so their
+    scores are masked and their V rows zeroed. The running (m, l, acc)
+    live in f32 VMEM scratch;
   * dequantizes int8 K/V tiles in VMEM right before the dot — the scale
     planes DMA alongside the codes, and the `code.astype(q.dtype) *
     scale.astype(q.dtype)` order mirrors the jnp oracle exactly — so int8
@@ -33,6 +37,14 @@ that whole window in jnp before the dot. This kernel instead:
     dequantized bf16 window;
   * accumulates the output per (row, head) without ever materializing the
     `(B, MB*bs, Hk, Dh)` gather.
+
+Tile sizes follow the shapes (`tile_plan`): a chunk is `P = 128 // bs`
+table entries (128 key positions, one lane-dense score tile; fewer where
+the table is narrower), and a program takes the most KV heads `ht`
+(a divisor of Hk) whose working set — q and output blocks, the f32
+(m, l, acc) scratch, the score tiles and the two KV slots — fits the
+VMEM budget (`vmem_bytes`). Decode and short spans take every head;
+a wide GQA prefill span (W*G = 1024 at Phi-3-medium width) takes fewer.
 
 GQA runs grouped: the G query heads of one kv head are flattened into the
 query-row axis `(W*G, Dh)`, so K/V tiles are fetched once per kv head, not
@@ -45,7 +57,7 @@ Tensor parallelism: the kernel needs no TP awareness. Under the
 shard_map serving step (api.engine with a "model"-axis mesh) it is
 invoked per shard with the PER-SHARD config — `Hk` here is
 num_kv_heads / tp and the pool ref is that shard's head-slice
-(runtime.kvblocks.pool_pspecs), so the grid is (B, Hk/tp, MB) and each
+(runtime.kvblocks.pool_pspecs), so the grid is (B, Hk/tp/ht) and each
 chip streams only its own heads' KV blocks. Attention is head-local,
 so no collective touches the kernel; the single psum per attention
 boundary happens outside, after the wo projection
@@ -57,81 +69,167 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import VMEM_BUDGET
+from repro.kernels.quant_matmul import tile_bytes
+
 NEG = -2.3819763e38  # large negative for masking in f32 (models.attention)
+CHUNK_KEYS = 128     # key positions per chunk: one lane-dense score tile
 
 
 def _softcap(s, cap: float):
     return (cap * jnp.tanh(s / cap)) if cap > 0 else s
 
 
-def _kernel(nb_ref, bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, bs, g, scale, cap,
-            ks_ref=None, vs_ref=None):
-    r, j = pl.program_id(0), pl.program_id(2)
+def chunk_blocks(block_size: int, table_width: int) -> int:
+    """Table entries one chunk of the kernel's walk covers (P)."""
+    return max(1, min(CHUNK_KEYS // block_size, table_width))
+
+
+def vmem_bytes(wg: int, dh: int, ht: int, p: int, bs: int, *,
+               q_itemsize: int, kv_itemsize: int, quant: bool) -> int:
+    """Scoped VMEM one program needs, at tile-padded sizes: the pipeline
+    double-buffers the q and output blocks; (m, l, acc) are f32 scratch;
+    K and V (and the int8 scale planes) hold two chunk slots; every
+    head's f32 scores, probabilities and dequantized K/V tiles are live
+    at once."""
+    io = 2 * 2 * tile_bytes(wg, dh, q_itemsize)
+    state = tile_bytes(wg, dh, 4) + 2 * tile_bytes(wg, 1, 4)
+    slots = 2 * 2 * p * tile_bytes(bs, dh, kv_itemsize)
+    if quant:
+        slots += 2 * 2 * p * tile_bytes(bs, 1, 4)
+    temps = 3 * tile_bytes(wg, p * bs, 4) + 2 * tile_bytes(p * bs, dh, 4)
+    return ht * (io + state + slots + temps)
+
+
+def tile_plan(wg: int, dh: int, hk: int, bs: int, table_width: int, *,
+              q_itemsize: int, kv_itemsize: int,
+              quant: bool) -> tuple[int, int]:
+    """(P, ht): table entries per chunk, and the most KV heads per program
+    (a divisor of hk) whose working set fits the VMEM budget; one head if
+    none does."""
+    p = chunk_blocks(bs, table_width)
+    for ht in range(hk, 0, -1):
+        if hk % ht == 0 and vmem_bytes(
+                wg, dh, ht, p, bs, q_itemsize=q_itemsize,
+                kv_itemsize=kv_itemsize, quant=quant) <= VMEM_BUDGET:
+            return p, ht
+    return p, 1
+
+
+def grid_blocks(nb, chunk: int) -> int:
+    """Block slots the kernel's chunks cover per layer and KV head,
+    Σ_r ceil(nb_r / P) * P, whether or not each slot holds valid context
+    (host side: the serving loop's `kv_grid_blocks` counter)."""
+    nb = np.asarray(nb, np.int64)
+    return int(((nb + chunk - 1) // chunk * chunk).sum())
+
+
+def _kernel(nb_ref, bt_ref, ctx_ref, q_ref, *refs, bs, g, p, ht, scale,
+            cap, quant):
+    n_kv = 4 if quant else 2
+    hbm, o_ref = refs[:n_kv], refs[n_kv]
+    bufs = refs[n_kv + 1:2 * n_kv + 1]
+    sem, m_ref, l_ref, acc_ref = refs[2 * n_kv + 1:]
+    r, h0 = pl.program_id(0), pl.program_id(1) * ht
     nb = nb_ref[r]
+    n_chunks = (nb + p - 1) // p
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def copies(c, slot, i):
+        # one block's K, V (and scale planes) for this program's heads
+        blk = bt_ref[r, c * p + i]
+        return [pltpu.make_async_copy(
+                    src.at[blk, pl.ds(h0, ht), :, pl.ds(0, dst.shape[-1])],
+                    dst.at[slot, :, i], sem.at[slot])
+                for src, dst in zip(hbm, bufs)]
 
-    @pl.when(j < nb)
-    def _block():
-        q = q_ref[0, 0]                                   # (WG, Dh)
-        k = k_ref[0, 0]                                   # (bs, Dh)
-        v = v_ref[0, 0]
-        if ks_ref is not None:
+    def load(buf, slot, n):
+        # (ht, P, bs, n): the slot's blocks, less the lane padding
+        x = buf[slot]
+        return x if x.shape[-1] == n else x[..., :n]
+
+    def each_block(c, slot, op):
+        # the chunk's valid entries only: min(P, nb - c*P) of them
+        def body(i, carry):
+            for cp in copies(c, slot, i):
+                op(cp)
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(p, nb - c * p), body, 0)
+
+    def start(c, slot):
+        each_block(c, slot, lambda cp: cp.start())
+
+    def wait(c, slot):
+        each_block(c, slot, lambda cp: cp.wait())
+
+    m_ref[...] = jnp.full_like(m_ref, NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(nb > 0)
+    def _first():
+        start(0, 0)
+
+    wg = q_ref.shape[2]
+    pb = p * bs
+
+    def chunk(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        # fused in-span causal mask: key slot c*P*bs+col visible to query
+        # row `row` (kv-head-grouped, q position row // G) iff
+        # slot <= ctx + pos; slots past the row's valid blocks were not
+        # copied this chunk, so their scores are masked and V rows zeroed
+        kpos = c * pb + jax.lax.broadcasted_iota(jnp.int32, (wg, pb), 1)
+        qpos = (ctx_ref[r]
+                + jax.lax.broadcasted_iota(jnp.int32, (wg, pb), 0) // g)
+        live = (kpos <= qpos) & (kpos < nb * bs)
+        filled = (c * pb + jax.lax.broadcasted_iota(jnp.int32, (pb, 1), 0)
+                  < nb * bs)
+        q = q_ref[0]                                      # (ht, WG, Dh)
+        dh = q.shape[-1]
+        k = load(bufs[0], slot, dh)                       # (ht, P, bs, Dh)
+        v = load(bufs[1], slot, dh)
+        if quant:
             # in-VMEM dequant right before the dot, mirroring the oracle's
-            # `codes.astype(q.dtype) * scales.astype(q.dtype)` order
-            k = k.astype(q.dtype) * ks_ref[0, 0].astype(q.dtype)
-            v = v.astype(q.dtype) * vs_ref[0, 0].astype(q.dtype)
-        s = jax.lax.dot_general(                          # (WG, bs) f32
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            # `codes.astype(q.dtype) * scales.astype(q.dtype)`
+            k = k.astype(q.dtype) * load(bufs[2], slot, 1).astype(q.dtype)
+            v = v.astype(q.dtype) * load(bufs[3], slot, 1).astype(q.dtype)
+        k = k.reshape(ht, pb, dh)
+        v = jnp.where(filled, v.reshape(ht, pb, dh), 0).astype(v.dtype)
+        s = jax.lax.dot_general(                          # (ht, WG, P*bs)
+            q, k, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale
-        s = _softcap(s, cap)
-        wg = s.shape[0]
-        # fused in-span causal mask: key slot j*bs+col visible to query row
-        # `row` (kv-head-grouped, q position row // G) iff slot <= ctx + pos
-        qpos = ctx_ref[r] + jax.lax.broadcasted_iota(jnp.int32, (wg, bs), 0) // g
-        kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (wg, bs), 1)
-        s = jnp.where(kpos <= qpos, s, NEG)
+        s = jnp.where(live, _softcap(s, cap), NEG)
         # online softmax update in f32
-        m_new = jnp.maximum(m_ref[...], jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_ref[...] - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
+            pr.astype(v.dtype), v,
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(j == jnp.maximum(nb - 1, 0))
-    def _finish():
-        # idle rows (nb == 0) never accumulated: l == 0 -> emit zeros, the
-        # caller discards them (same contract as the oracle's garbage rows)
-        l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
-
-
-def kernel_grid(rows: int, kv_heads: int, table_width: int) -> tuple:
-    """The kernel's grid: one program per (row, KV head, table slot)."""
-    return (rows, kv_heads, table_width)
-
-
-def grid_blocks(rows: int, table_width: int) -> int:
-    """Grid steps the kernel runs per layer and KV head, each one block
-    slot of a row's table whether or not it holds valid context (host
-    side: the serving loop's `kv_grid_blocks` counter)."""
-    r, _, w = kernel_grid(rows, 1, table_width)
-    return r * w
+    jax.lax.fori_loop(0, n_chunks, chunk, 0)
+    # idle rows (nb == 0) never accumulated: l == 0 -> emit zeros, the
+    # caller discards them (same contract as the oracle's garbage rows)
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -157,6 +255,8 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
     g = h // hk
     wg = w * g
     quant = "ks" in pool
+    p, ht = tile_plan(wg, dh, hk, bs, mb, q_itemsize=q.dtype.itemsize,
+                      kv_itemsize=pool["k"].dtype.itemsize, quant=quant)
 
     from repro.runtime.kvblocks import valid_block_counts
 
@@ -165,62 +265,46 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
     # flattened row i sits at query position i // G
     qh = (q.reshape(b, w, hk, g, dh).transpose(0, 2, 1, 3, 4)
           .reshape(b, hk, wg, dh))
-    bt = block_table.astype(jnp.int32)
+    keys = ("k", "v", "ks", "vs") if quant else ("k", "v")
+    leaves = [pool[key] for key in keys]
 
-    def q_map(r, h_, j, nb_, bt_, ctx_):
-        return (r, h_, 0, 0)
+    def lanes(n):
+        # the TPU lays a leaf's last axis out in whole 128-lane tiles, and
+        # the compiler slices HBM refs in whole tiles only, so a compiled
+        # copy moves the padded tile and the kernel drops the pad lanes
+        return n if interpret else -(-n // 128) * 128
 
-    def kv_map(r, h_, j, nb_, bt_, ctx_):
-        # clamp past-the-end steps onto the last valid block: the index
-        # map returns the same physical block as the previous step, so the
-        # pipeline skips the DMA — only valid context ever streams
-        jj = jnp.maximum(jnp.minimum(j, nb_[r] - 1), 0)
-        return (bt_[r, jj], h_, 0, 0)
-
-    # one (bs, Dh) tile of one head per step: the block's last two dims
-    # are the pool's own, which the TPU's tiling accepts at any bs
-    kv_specs = [
-        pl.BlockSpec((1, 1, bs, dh), kv_map),
-        pl.BlockSpec((1, 1, bs, dh), kv_map),
-    ]
-    operands = [qh, pool["k"], pool["v"]]
-    if quant:
-        kv_specs += [pl.BlockSpec((1, 1, bs, 1), kv_map),
-                     pl.BlockSpec((1, 1, bs, 1), kv_map)]
-        operands += [pool["ks"], pool["vs"]]
-
-    def kernel(*refs):
-        if quant:
-            nb_r, bt_r, ctx_r, q_r, k_r, v_r, ks_r, vs_r, o_r, m_r, l_r, a_r = refs
-            _kernel(nb_r, bt_r, ctx_r, q_r, k_r, v_r, o_r, m_r, l_r, a_r,
-                    bs=bs, g=g, scale=dh ** -0.5, cap=logit_softcap,
-                    ks_ref=ks_r, vs_ref=vs_r)
-        else:
-            nb_r, bt_r, ctx_r, q_r, k_r, v_r, o_r, m_r, l_r, a_r = refs
-            _kernel(nb_r, bt_r, ctx_r, q_r, k_r, v_r, o_r, m_r, l_r, a_r,
-                    bs=bs, g=g, scale=dh ** -0.5, cap=logit_softcap)
+    def q_map(r, hg, *_):
+        return (r, hg, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # nb, block_table, ctx_lens
-        grid=kernel_grid(b, hk, mb),
-        in_specs=[pl.BlockSpec((1, 1, wg, dh), q_map)] + kv_specs,
-        out_specs=pl.BlockSpec((1, 1, wg, dh), q_map),
+        grid=(b, hk // ht),
+        in_specs=[pl.BlockSpec((1, ht, wg, dh), q_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(leaves),
+        out_specs=pl.BlockSpec((1, ht, wg, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((wg, 1), jnp.float32),    # running max m
-            pltpu.VMEM((wg, 1), jnp.float32),    # running denom l
-            pltpu.VMEM((wg, dh), jnp.float32),   # running numerator acc
+            pltpu.VMEM((2, ht, p, bs, lanes(leaf.shape[-1])), leaf.dtype)
+            for leaf in leaves
+        ] + [
+            pltpu.SemaphoreType.DMA((2,)),       # one per chunk slot
+            pltpu.VMEM((ht, wg, 1), jnp.float32),    # running max m
+            pltpu.VMEM((ht, wg, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((ht, wg, dh), jnp.float32),   # running numerator acc
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_kernel, bs=bs, g=g, p=p, ht=ht,
+                          scale=dh ** -0.5, cap=logit_softcap, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, wg, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
         name="paged_attention",
-    )(nb, bt, ctx_lens.astype(jnp.int32), *operands)
+    )(nb, block_table.astype(jnp.int32), ctx_lens.astype(jnp.int32), qh,
+      *leaves)
     return (out.reshape(b, hk, w, g, dh).transpose(0, 2, 1, 3, 4)
             .reshape(b, w, h, dh))
 
@@ -239,16 +323,15 @@ def stream_hbm_bytes(ctx_lens, q_lens, block_size: int, hk: int, dh: int,
                      *, kv_bits: int = 16, n_q_heads: int | None = None
                      ) -> int:
     """Modeled HBM traffic of one paged_attention launch: each row streams
-    ceil((ctx+q)/bs) KV blocks ONCE (idle q_lens == 0 rows stream just
-    the single trash block their clamped index map lands on), plus the q
-    tile in and the output tile back. This is the O(ctx) term the kernel
-    converts serving attention to — compare `gather_hbm_bytes` for what
-    the jnp path moves."""
+    ceil((ctx+q)/bs) KV blocks ONCE (idle q_lens == 0 rows stream
+    nothing), plus the q tile in and the output tile back. This is the
+    O(ctx) term the kernel converts serving attention to — compare
+    `gather_hbm_bytes` for what the jnp path moves."""
     h = n_q_heads or hk
     per_tok = kv_bytes_per_token(hk, dh, kv_bits)
     total = 0
     for ctx, ql in zip(ctx_lens, q_lens):
-        nb = 1 if ql <= 0 else -(-(int(ctx) + int(ql)) // block_size)
+        nb = 0 if ql <= 0 else -(-(int(ctx) + int(ql)) // block_size)
         total += nb * block_size * per_tok
     w = max((int(x) for x in q_lens), default=0)
     io = 2 * len(list(ctx_lens)) * w * h * dh * 2     # q in + o out (bf16)

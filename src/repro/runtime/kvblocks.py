@@ -302,7 +302,7 @@ def shard_pool(pool, tp: int, shard: int):
 
 def valid_block_counts(ctx_lens, q_lens, block_size, max_blocks):
     """Per-row count of block-table entries holding valid context THIS
-    step — the grid metadata the Pallas paged-attention kernel walks.
+    step — the metadata the Pallas paged-attention kernel walks.
 
     Row r's span writes its K/V first, so after the scatter the pool holds
     `ctx_lens[r] + q_lens[r]` valid positions = the first
@@ -310,7 +310,7 @@ def valid_block_counts(ctx_lens, q_lens, block_size, max_blocks):
     trash-block-0 padding the kernel must never fetch. Idle rows
     (q_lens == 0) count zero — the kernel skips them entirely. Pure index
     math on numpy or jax arrays alike: the serving step traces it for the
-    kernel's grid, and the serve loop's step counters run it on the host
+    kernel's walk, and the serve loop's step counters run it on the host
     copy of the same columns. Clamped to the table width for
     caller-supplied out-of-range metadata."""
     total = ctx_lens + q_lens

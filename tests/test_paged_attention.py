@@ -41,10 +41,14 @@ def _mk_cfg(**kw):
 def _mk_state(cfg, key, *, B=3, W=4, MB=4, bs=4,
               ctx=(5, 0, 9), ql=(3, 0, 1), poison=None):
     """Params, a pre-populated single-layer pool, block tables, and a span
-    batch: row 0 = mid-prompt chunk, row 1 = idle, row 2 = decode."""
+    batch: row 0 = mid-prompt chunk, row 1 = idle, row 2 = decode.
+
+    Table entries past a row's valid blocks point at the reserved trash
+    block 0 or at the pool's last block, which no row owns; `poison`
+    fills both."""
     ks = jax.random.split(key, 6)
     params = attn.attn_init(ks[0], cfg, jnp.dtype(cfg.dtype))
-    nb_pool = 1 + sum(-(-(c + q) // bs) for c, q in zip(ctx, ql))
+    nb_pool = 2 + sum(-(-(c + q) // bs) for c, q in zip(ctx, ql))
     pool = {k: v[0] for k, v in kvblocks.init_paged_cache(
         dataclasses.replace(cfg, num_layers=1), nb_pool, bs).items()}
     if "ks" in pool:
@@ -60,14 +64,16 @@ def _mk_state(cfg, key, *, B=3, W=4, MB=4, bs=4,
         pool["k"] = jax.random.normal(ks[1], pool["k"].shape, dt)
         pool["v"] = jax.random.normal(ks[2], pool["v"].shape, dt)
     if poison is not None:
-        # blocks the kernel must never read: the reserved trash block
-        pool["k"] = pool["k"].at[0].set(poison)
-        pool["v"] = pool["v"].at[0].set(poison)
+        # blocks the kernel must never read: the reserved trash block and
+        # the unowned block that padding entries inside a chunk point at
+        pool["k"] = pool["k"].at[0].set(poison).at[-1].set(poison)
+        pool["v"] = pool["v"].at[0].set(poison).at[-1].set(poison)
     bt = np.zeros((len(ctx), MB), np.int32)
     nxt = 1
     for r, (c, q) in enumerate(zip(ctx, ql)):
         need = -(-(c + q) // bs)
         bt[r, :need] = np.arange(nxt, nxt + need)
+        bt[r, need:need + 1] = nb_pool - 1
         nxt += need
     x = jax.random.normal(ks[5], (B, W, cfg.d_model), jnp.dtype(cfg.dtype))
     return (params, pool, jnp.asarray(bt), jnp.asarray(ctx, jnp.int32),
@@ -118,10 +124,12 @@ def test_kernel_matches_oracle_int8_kv_and_softcap():
 
 
 def test_kernel_never_reads_trash_or_invalid_blocks():
-    """Poison the reserved trash block with huge values: the kernel's
-    output must equal the clean-pool output bit for bit — proof the DMA
-    walk never touches table padding (the oracle relies on masking
-    instead; both must agree on the valid region either way)."""
+    """Poison the reserved trash block, and the block that table entries
+    just past each row's valid count point at (inside the chunk the
+    kernel fetches), with huge values: the kernel's output must equal the
+    clean-pool output bit for bit — proof the DMA walk never touches
+    table padding (the oracle relies on masking instead; both must agree
+    on the valid region either way)."""
     cfg = _mk_cfg()
     clean = _mk_state(cfg, jax.random.PRNGKey(2))
     poisoned = _mk_state(cfg, jax.random.PRNGKey(2), poison=1e30)
@@ -130,6 +138,70 @@ def test_kernel_never_reads_trash_or_invalid_blocks():
     yk_poison, _ = attn.span_attention_paged(params, x, pool, bt, ctx, ql,
                                              cfg, impl="kernel")
     _assert_span_close(yk_clean, yk_poison, ql, rtol=0, atol=0)
+
+
+def _chunked_state(kv, g, w, key):
+    """Rows at nb = 0, 1, P-1, P, P+1 and 3P valid blocks in a table of
+    MB = 3P entries (bs 16, P = 8 table entries per chunk): idle, a lone
+    block, partial and full single chunks, a partial last chunk, and a
+    full table. Physical blocks are shuffled; the pool's last block is
+    unowned. Each span is as wide as the row allows, up to w."""
+    bs = 16
+    p = pa.chunk_blocks(bs, 10 ** 6)
+    mb = 3 * p
+    nbs = (0, 1, p - 1, p, p + 1, 3 * p)
+    ql = [min(w, n * bs) if n else 0 for n in nbs]
+    ctx = [n * bs - q if n else 0 for n, q in zip(nbs, ql)]
+    dtype = {"f32": "float32"}.get(kv, "bfloat16")
+    cfg = _mk_cfg(dtype=dtype, num_heads=2 * g, num_kv_heads=2,
+                  head_dim=16, kv_cache_bits=8 if kv == "int8" else 16,
+                  logit_softcap=30.0 if kv == "int8" else 0.0)
+    n_blocks = 2 + sum(nbs)
+    ks = jax.random.split(key, 5)
+    shp = (n_blocks, 2, bs, 16)
+    if kv == "int8":
+        pool = {"k": jax.random.randint(ks[0], shp, -127, 128, jnp.int8),
+                "v": jax.random.randint(ks[1], shp, -127, 128, jnp.int8),
+                "ks": jax.random.uniform(ks[2], shp[:3] + (1,),
+                                         jnp.float32, 0.01, 0.1),
+                "vs": jax.random.uniform(ks[3], shp[:3] + (1,),
+                                         jnp.float32, 0.01, 0.1)}
+    else:
+        pool = {"k": jax.random.normal(ks[0], shp, jnp.dtype(dtype)),
+                "v": jax.random.normal(ks[1], shp, jnp.dtype(dtype))}
+    ids = np.random.default_rng(0).permutation(np.arange(1, n_blocks - 1))
+    bt = np.zeros((len(nbs), mb), np.int32)
+    at = 0
+    for r, n in enumerate(nbs):
+        bt[r, :n] = ids[at:at + n]
+        at += n
+    q = jax.random.normal(ks[4], (len(nbs), w, 2 * g, 16), jnp.dtype(dtype))
+    return (cfg, q, pool, jnp.asarray(bt), jnp.asarray(ctx, jnp.int32),
+            jnp.asarray(ql, jnp.int32), nbs)
+
+
+@pytest.mark.parametrize("w", [1, 8])
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
+def test_kernel_matches_oracle_across_chunks(kv, g, w):
+    """Tables three chunks wide, rows ending inside, at and just past a
+    chunk boundary: the chunked walk (double-buffered copies, a partial
+    last chunk masked in the scores) matches the gather oracle on every
+    valid position, for f32, bf16 and int8 KV (with soft-cap), plain and
+    grouped heads, decode and span widths; idle rows stay zero."""
+    cfg, q, pool, bt, ctx, ql, nbs = _chunked_state(
+        kv, g, w, jax.random.PRNGKey(7))
+    np.testing.assert_array_equal(
+        np.asarray(kvblocks.valid_block_counts(ctx, ql, 16, bt.shape[1])),
+        nbs)
+    pos = ctx[:, None] + jnp.arange(w)[None, :]
+    yr = attn._span_attend_gather(q, pool, bt, pos, cfg)
+    yk = pa.paged_attention(q, pool, bt, ctx, ql,
+                            logit_softcap=cfg.logit_softcap, interpret=True)
+    tol = {"f32": (2e-5, 2e-5), "bf16": (2e-2, 2e-2),
+           "int8": (2e-2, 1e-1)}[kv]
+    _assert_span_close(yr, yk, ql, rtol=tol[0], atol=tol[1])
+    np.testing.assert_array_equal(np.asarray(yk[0], np.float32), 0.0)
 
 
 def test_idle_rows_emit_zeros_and_skip_work():
@@ -217,9 +289,8 @@ def test_stream_bytes_scale_with_ctx_not_pool():
                              kv_bits=8)
     g8 = pa.gather_hbm_bytes(b, mb, bs, hk, dh, kv_bits=8, w=8)
     assert s8 / g8 < long_ / gather
-    # idle rows stream exactly one (trash) block, never their stale ctx
-    assert (pa.stream_hbm_bytes([100], [0], bs, hk, dh)
-            == bs * pa.kv_bytes_per_token(hk, dh, 16))
+    # idle rows stream nothing, never their stale ctx
+    assert pa.stream_hbm_bytes([100], [0], bs, hk, dh) == 0
 
 
 def test_tpu_model_prices_paged_attention():

@@ -16,6 +16,7 @@ from repro.api import DraftSpec, InferenceEngine, Request
 from repro.api import engine as engine_mod
 from repro.configs import get_config
 from repro.core.compress import CompressionConfig
+from repro.kernels import paged_attention as pa
 from repro.runtime import kvblocks, tracing
 from repro.runtime import sampling as smp
 
@@ -121,7 +122,9 @@ def test_serve_spans_and_counters(engine, loop, tmp_path, monkeypatch):
         nb = counts(jnp.asarray(buf[:, ctx_col]),
                     jnp.asarray(buf[:, ctx_col + 1]), 4, mb)
         assert s[4]["kv_blocks_valid"] == int(nb.sum())
-        assert s[4]["kv_grid_blocks"] == res.max_batch * mb
+        p = pa.chunk_blocks(4, mb)
+        assert s[4]["kv_grid_blocks"] == int(
+            sum(-(-int(n) // p) * p for n in np.asarray(nb)))
         assert s[4]["kv_blocks_valid"] <= s[4]["kv_grid_blocks"]
 
     readbacks = [s for s in spans if s[1] == "repro.serve.readback"]

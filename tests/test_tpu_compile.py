@@ -165,6 +165,33 @@ def test_paged_attention_compiles(one_chip, kv, block_size):
     assert _kernels(compiled) == {"paged_attention"}
 
 
+@pytest.mark.parametrize("w", [1, 256], ids=["decode", "span256"])
+def test_paged_attention_compiles_phi3m_width(one_chip, monkeypatch, w):
+    """Phi-3-medium's attention (10 KV heads of 128, G 4, bf16 pool,
+    bs 16) over 32 rows of 128-entry tables: decode takes every head per
+    program, the widest span (W*G = 1024) fewer. Compiled under a scoped
+    VMEM limit of exactly the kernel's own model of its working set, so
+    a pass also shows that model is an upper bound."""
+    from repro.kernels import paged_attention as pa
+
+    b, h, hk, dh, bs, mb = 32, 40, 10, 128, 16, 128
+    p, ht = pa.tile_plan(w * h // hk, dh, hk, bs, mb, q_itemsize=2,
+                         kv_itemsize=2, quant=False)
+    assert ht == (hk if w == 1 else 2)
+    _within_modeled_vmem(monkeypatch, pa.vmem_bytes(
+        w * h // hk, dh, ht, p, bs, q_itemsize=2, kv_itemsize=2,
+        quant=False))
+    nb = b * mb + 1
+    pool = {key: _spec(one_chip, (nb, hk, bs, dh), jnp.bfloat16)
+            for key in ("k", "v")}
+    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
+    compiled = jax.jit(
+        lambda *a: pa.paged_attention(*a, interpret=False)).lower(
+        _spec(one_chip, (b, w, h, dh), jnp.bfloat16), pool,
+        i32((b, mb)), i32((b,)), i32((b,))).compile()
+    assert _kernels(compiled) == {"paged_attention"}
+
+
 @pytest.mark.parametrize("tp", [1, 4])
 def test_itera_w4_serving_step_compiles(topo, monkeypatch, tp):
     """The whole fused serving step of full-width opus-mt under a packed
