@@ -3,8 +3,15 @@
 Everything that belongs to one configuration, traffic mix or metric sits
 in a file of its own that this module finds by name:
 
-* `configs/<name>.json` — the configuration as it is run ("model",
-  "plan", "serving", "max_positions"), beside its published keys;
+* `configs/<name>.json` — the configuration as it is run ("family",
+  "model", "plan", "serving", "max_positions"), beside its published
+  keys;
+* `families/<name>.py` — a model family: the program's config, the
+  served tree, the plain reference and the work of each kernel (see
+  `families/__init__.py`);
+* `methods/<name>.py` — a plan method, named by the plan's "method": a
+  site's shapes, arrays, program node, reference linear, kernel and work
+  (see `methods/__init__.py`);
 * `traffic/<name>.json` — a mix of request sizes for `traffic.py`;
 * `metrics/<name>.py` — one metric, `read(ctx)` returning a number or
   None (nothing to read in this run: the metric is left out);
@@ -31,8 +38,8 @@ import time
 import numpy as np
 
 import check
-import synth
 import devtrace
+import families
 import traffic
 import work
 
@@ -66,8 +73,10 @@ class Cell:
 
     @property
     def cfg(self) -> dict:
-        """What synth, reference and work read: model sizes and plan."""
-        return {**self.config["model"], "plan": self.config["plan"]}
+        """What the family's tree, reference and work read: model sizes,
+        plan and family."""
+        return {**self.config["model"], "plan": self.config["plan"],
+                "family": self.config["family"]}
 
 
 def _reports(metric: dict, cell: str, e2e_names=None) -> bool:
@@ -133,12 +142,12 @@ def configure_jax(cell: Cell) -> None:
 # ------------------------------------------------------------ engine --
 def build_engine(cell: Cell, seed: int):
     from repro.api import InferenceEngine
-    from repro.configs.base import ModelConfig
 
-    tree = synth.build_program_tree(seed, cell.cfg)
+    family = families.load(cell.config["family"])
+    tree = family.program_tree(seed, cell.cfg)
     s = cell.config["serving"]
     return InferenceEngine.build(
-        ModelConfig(name=cell.config["name"], **cell.config["model"]), None,
+        family.program_config(cell.config), None,
         params=tree, max_batch=s["max_batch"], block_size=s["block_size"],
         chunk_tokens=s["chunk_tokens"])
 
@@ -355,18 +364,32 @@ WINDOWS = {"bursts": window_bursts, "job": window_job}
 def in_window(w: Window, lo: float, hi: float) -> work.Tokens:
     """Valid rows processed between host times lo and hi: the prompt of
     every request whose first token came in the interval, and each served
-    token after the first (it was fed back as a decode input)."""
-    prompts, decode, emitted = [], [], 0
+    token after the first (it was fed back as a decode input); by request
+    too, with its token ids and the step of each row, a step known by the
+    time its tokens were read back."""
+    prompts, decode, emitted, held = [], [], 0, []
     for s in w.rec.reqs:
         p = len(s.prompt)
+        pos, when = [], []
         for j, t in enumerate(s.times):
             if lo <= t < hi:
                 emitted += 1
                 if j == 0:
                     prompts.append(p)
+                    pos += range(p)
+                    when += [t] * p
                 else:
                     decode.append(p + j - 1)
-    return work.Tokens(prompts, decode, emitted)
+                    pos.append(p + j - 1)
+                    when.append(t)
+        if pos:
+            held.append((s, pos, when))
+    step = {t: i for i, t in enumerate(sorted(
+        {t for _, _, when in held for t in when}))}
+    reqs = [work.Rows(np.concatenate([s.prompt, s.tokens]).astype(np.int32),
+                      pos, [step[t] for t in when])
+            for s, pos, when in held]
+    return work.Tokens(prompts, decode, emitted, reqs)
 
 
 # ---------------------------------------------------------------- run --
@@ -424,7 +447,7 @@ def run(name: str, seed: int, seconds: float, trace: bool,
     compared, within = check.judge(check.load_limits(cell.name), got)
     correct = failed == 0 and bool(items) and within
 
-    ctx = {"cell": cell, "window": w, "setup_s": setup_s,
+    ctx = {"cell": cell, "window": w, "setup_s": setup_s, "seed": seed,
            "weight_hbm_bytes": weight_bytes, "peaks": peaks, "cfg": cell.cfg}
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": peak_bytes}

@@ -2,8 +2,9 @@
 
 After the window closes and the program's state is freed, a sample of
 the finished requests drawn from the seed (the longest finished request
-always among them) is run through `reference` once each, over its prompt
-followed by its served tokens. At every served position the reading is
+always among them) is run once each through the plain reference of the
+configuration's family (`families/<name>.py`), over its prompt followed
+by its served tokens. At every served position the reading is
 the gap by which the served token's reference logit lies below the
 reference's best logit there: 0 where the program picked the reference's
 own greedy token. A run is correct when every served request is well
@@ -24,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import reference
+import families
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -79,25 +80,26 @@ def readings(seed: int, cfg: dict, items: list, lower=None) -> dict:
     `items` ((prompt, outputs) pairs); with `lower`, also {"lower": {...}}:
     the control, the reference with every activation rounded to that
     float type, read at the same positions."""
+    fam = families.load(cfg["family"])
     seqs = [np.concatenate([p, o[:-1]]).astype(np.int32) for p, o in items]
     width = _pad_width(seqs)
     seqs_p = [np.pad(s, (0, width - len(s))) for s in seqs]
-    hs = reference.hidden_states(seed, cfg, seqs_p)
+    hs = fam.hidden_states(seed, cfg, seqs_p)
     ref = []
     for h, (p, o) in zip(hs, items):
         pos, tok = _served_positions(p, o, width)
-        best, got, _ = _position_reads(reference.logits(seed, cfg, h),
+        best, got, _ = _position_reads(fam.logits(seed, cfg, h),
                                        pos, tok)
         ref.append((np.asarray(best)[:len(o)], np.asarray(got)[:len(o)]))
     out = {"served": _summary([b - g for b, g in ref])}
     if lower is not None:
-        hc = reference.hidden_states(seed, cfg, seqs_p, lower=lower)
+        hc = fam.hidden_states(seed, cfg, seqs_p, lower=lower)
         gaps = []
         for h_ref, h_c, (p, o), (best, _) in zip(hs, hc, items, ref):
             pos, tok = _served_positions(p, o, width)
-            _, _, pick = _position_reads(reference.logits(seed, cfg, h_c),
+            _, _, pick = _position_reads(fam.logits(seed, cfg, h_c),
                                          pos, tok)
-            _, got, _ = _position_reads(reference.logits(seed, cfg, h_ref),
+            _, got, _ = _position_reads(fam.logits(seed, cfg, h_ref),
                                         pos, pick)
             gaps.append(best - np.asarray(got)[:len(o)])
         out["lower"] = _summary(gaps)

@@ -1,14 +1,15 @@
-"""Plain reference of the served model, in float32 `jax.numpy`.
+"""Plain reference of the served model, in float32 `jax.numpy`: what
+every model family shares.
 
-A decoder-only pre-norm transformer as the configuration files describe
-it: token embedding scaled by sqrt(d_model) (rounded to the model dtype),
-sinusoidal or interleaved-pair rotary positions, causal grouped-query
-attention, a SwiGLU or tanh-GELU MLP, RMSNorm (stored as gamma - 1) or
-LayerNorm, and an untied output head. Every linear is the uniform ITERA
-plan's math: the input row quantized to `act_wl` bits (one scale per
-row), an exact integer product with the W1 codes, the per-rank scales
-folded in, the intermediate requantized per row, an exact integer product
-with the W2 codes.
+A decoder-only model as the configuration files describe it: token
+embedding scaled by sqrt(d_model) (rounded to the model dtype),
+sinusoidal or interleaved-pair rotary positions, RMSNorm (stored as
+gamma - 1) or LayerNorm, and an untied output head; the layers between
+are the family's (`families/<name>.py`), run one at a time across
+sequences by `hidden_states`, and its output head is the site the family
+names to `logits`. Every linear is its plan method's math
+(`methods/<name>.py` `linear`), built from the row quantization
+and the exact integer products here.
 
 It imports nothing of the program and takes nothing the program made: the
 weights are regenerated from the seed by `synth`, one layer at a time,
@@ -30,37 +31,33 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import methods
 import synth
 
 HI = jax.lax.Precision.HIGHEST
 
 
-def _rowquant(x, qm):
+def rowquant(x, qm):
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     s = jnp.where(absmax > 0, absmax / qm, 1.0)
     return jnp.clip(jnp.round(x / s), -qm, qm), s
 
 
-def _int_dot(a, b):
+def int_dot(a, b):
     return jnp.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
                    preferred_element_type=jnp.float32)
 
 
-def _codes(v, packed):
+def codes(v, packed):
     return synth.unpack(v) if packed else v
 
 
-def itera_linear(x, arrays, shp, act_wl):
-    v1, s1, v2, s2 = arrays
-    qm = 2 ** (act_wl - 1) - 1
-    xq, sx = _rowquant(x, qm)
-    t = _int_dot(xq, _codes(v1, shp["w1_packed"])) * sx * s1 * s2.reshape(
-        1, -1)
-    tq, st = _rowquant(t, qm)
-    return _int_dot(tq, _codes(v2, shp["w2_packed"])) * st
+def linear(x, arrays, shp, act_wl):
+    """One site's linear under its plan method (`synth.shapes`)."""
+    return methods.load(shp["method"]).linear(x, arrays, shp, act_wl)
 
 
-def _norm(x, p, cfg):
+def norm(x, p, cfg):
     eps = cfg["norm_eps"]
     if cfg["norm"] == "layernorm":
         mu = jnp.mean(x, -1, keepdims=True)
@@ -71,7 +68,7 @@ def _norm(x, p, cfg):
     return x * jax.lax.rsqrt(var + eps) * (1.0 + p["gamma"])
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x: (S, H, Dh); rotates pairs (2i, 2i+1)."""
     hd = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -82,70 +79,20 @@ def _rope(x, pos, theta):
                      axis=-1).reshape(x.shape)
 
 
-def _sinusoid(pos, d):
+def sinusoid(pos, d):
     half = d // 2
     inv = 1.0 / (10000.0 ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos[:, None].astype(jnp.float32) * inv
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
-def _layer_weights(key, cfg, layer):
-    raw = {}
-    for site, (_, _, stacked) in synth.sites(cfg).items():
-        if stacked:
-            raw[site] = synth.site_arrays(key, synth.site_shapes(cfg, site),
-                                          site, layer)
-    norms = {"ln1": synth.norm_params(cfg), "ln2": synth.norm_params(cfg)}
-    return raw, norms
-
-
-def _rounder(lower):
+def rounder(lower):
     """Round to the `lower` float type and back (saturating), or nothing."""
     if lower is None:
         return lambda x: x
     big = float(jnp.finfo(lower).max)
     return lambda x: jnp.clip(x, -big, big).astype(lower).astype(
         jnp.float32)
-
-
-def _block(cfg, raw, norms, rnd, h):
-    """One layer over one whole sequence h: (S, d) f32."""
-    lin = lambda x, s: rnd(itera_linear(                       # noqa: E731
-        x, raw[s], synth.site_shapes(cfg, s), cfg["plan"]["act_wl"]))
-    S = h.shape[0]
-    H, Hk, hd = cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"]
-    pos = jnp.arange(S)
-    x = rnd(_norm(h, norms["ln1"], cfg))
-    q = lin(x, "attn/wq").reshape(S, H, hd)
-    k = lin(x, "attn/wk").reshape(S, Hk, hd)
-    v = lin(x, "attn/wv").reshape(S, Hk, hd)
-    if cfg["pos_emb"] == "rope":
-        q = rnd(_rope(q, pos, cfg["rope_theta"]))
-        k = rnd(_rope(k, pos, cfg["rope_theta"]))
-    g = H // Hk
-    kk = jnp.repeat(k, g, axis=1)
-    vv = jnp.repeat(v, g, axis=1)
-    s = jnp.einsum("qhd,khd->hqk", q, kk, precision=HI) * hd ** -0.5
-    s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
-    p = rnd(jax.nn.softmax(s, axis=-1))
-    o = rnd(jnp.einsum("hqk,khd->qhd", p, vv, precision=HI))
-    h = rnd(h + lin(o.reshape(S, H * hd), "attn/wo"))
-    x = rnd(_norm(h, norms["ln2"], cfg))
-    if cfg["mlp_act"] == "swiglu":
-        m = rnd(jax.nn.silu(lin(x, "mlp/gate")) * lin(x, "mlp/up"))
-    else:
-        m = rnd(jax.nn.gelu(lin(x, "mlp/up"), approximate=True))
-    return rnd(h + lin(m, "mlp/down"))
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _layer(cfg_json, lower, key, layer, hs):
-    """One layer over every sequence: hs (n, S, d) f32."""
-    cfg = json.loads(cfg_json)
-    raw, norms = _layer_weights(key, cfg, layer)
-    with jax.default_matmul_precision("highest"):
-        return jax.vmap(lambda h: _block(cfg, raw, norms,
-                                         _rounder(lower), h))(hs)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -155,27 +102,27 @@ def _embed(cfg_json, lower, key, tokens):
     scale = jnp.asarray(cfg["d_model"] ** 0.5, jnp.dtype(cfg["dtype"]))
     h = table[tokens].astype(jnp.float32) * scale.astype(jnp.float32)
     if cfg["pos_emb"] == "sinusoidal":
-        h = h + _sinusoid(jnp.arange(tokens.shape[0]), cfg["d_model"])
-    return _rounder(lower)(h)
+        h = h + sinusoid(jnp.arange(tokens.shape[0]), cfg["d_model"])
+    return rounder(lower)(h)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _head(cfg_json, key, h):
-    """Final norm and output head: (S, d) -> (S, V) f32 logits."""
-    cfg = json.loads(cfg_json)
-    shp = synth.site_shapes(cfg, "lm_head")
-    arrays = synth.site_arrays(key, shp, "lm_head", 0)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _head(cfg_json, site, shp_json, key, h):
+    """Final norm and the output head `site`: (S, d) -> (S, V) f32."""
+    cfg, shp = json.loads(cfg_json), json.loads(shp_json)
+    arrays = synth.site_arrays(key, shp, site, 0)
     with jax.default_matmul_precision("highest"):
-        x = _norm(h, synth.norm_params(cfg), cfg)
-        return itera_linear(x, arrays, shp, cfg["plan"]["act_wl"])
+        x = norm(h, synth.norm_params(cfg), cfg)
+        return linear(x, arrays, shp, cfg["plan"]["act_wl"])
 
 
-def hidden_states(seed: int, cfg: dict, seqs: list[np.ndarray], *,
+def hidden_states(layer_fn, seed: int, cfg: dict, seqs: list[np.ndarray],
                   lower=None) -> list:
     """Last-layer hidden states (S_i, d) of each whole sequence, one
     layer at a time across all sequences (each padded to a common
     length; padding sits after the real tokens, which causal attention
-    never lets them see)."""
+    never lets them see). `layer_fn(cfg_json, lower, key, layer, hs)` is
+    the family's layer over every sequence, hs (n, S, d) f32."""
     cj = json.dumps(cfg, sort_keys=True)
     lo = jnp.dtype(lower) if lower else None
     key = synth.seed_key(seed)
@@ -183,9 +130,12 @@ def hidden_states(seed: int, cfg: dict, seqs: list[np.ndarray], *,
     hs = jnp.stack([_embed(cj, lo, key, jnp.asarray(
         np.pad(s, (0, width - len(s))))) for s in seqs])
     for layer in range(cfg["num_layers"]):
-        hs = _layer(cj, lo, key, jnp.int32(layer), hs)
+        hs = layer_fn(cj, lo, key, jnp.int32(layer), hs)
     return [h[:len(s)] for h, s in zip(hs, seqs)]
 
 
-def logits(seed: int, cfg: dict, h):
-    return _head(json.dumps(cfg, sort_keys=True), synth.seed_key(seed), h)
+def logits(seed: int, cfg: dict, h, site: str, shp: dict):
+    """Logits of hidden states h through the family's output head: the
+    final norm, then `site` of shapes `shp` (one layer, unstacked)."""
+    return _head(json.dumps(cfg, sort_keys=True), site,
+                 json.dumps(shp, sort_keys=True), synth.seed_key(seed), h)

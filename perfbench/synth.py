@@ -1,9 +1,10 @@
-"""Weights made from `--seed`, directly in the serving format of a uniform
-packed ITERA plan: per linear site a rank-R pair of W4 factor codes with
-one f32 scale per rank vector, W1 (K, R) packed along R and W2 (R, N)
-packed along N wherever that axis is whole 256-column tiles (else an int8
-carrier), the layout named `int4-halves-256`: byte j of a 256-column tile
-holds column j in its low nibble and column j + 128 in its high nibble.
+"""Weights made from `--seed`, directly in the serving format of the
+configuration's plan, W4 codes in the layout named `int4-halves-256`
+(byte j of a 256-column tile holds column j in its low nibble and column
+j + 128 in its high nibble) wherever the packed axis is whole 256-column
+tiles, else in an int8 carrier. The plan's method (`methods/<name>.py`)
+decides a site's form: its shapes, the code matrices and scales it
+draws, and the program's node that holds them.
 
 The served work depends only on shapes, word lengths, ranks and packing,
 so the codes are random (uniform over the W4 range [-7, 7]) rather than
@@ -11,20 +12,20 @@ decomposed from a trained matrix. Scales are set so that each factor
 has unit gain on unit-variance input, which keeps the residual stream
 O(1) and the logits meaningful.
 
-`make_raw` builds the whole tree as plain arrays in one jitted call on
-the device. `site_arrays` regenerates one layer of one site alone, bit
-for bit the same, which is how the reference gets its weights without
-taking anything the program holds. Nothing here imports the program;
-`to_program_tree` (the one place that does) wraps the arrays in the
-program's node types.
+This module holds what every model family shares; a family
+(`families/<name>.py`) lists its sites and builds its whole tree in one
+jitted call on the device. `site_arrays` regenerates one layer of one
+site alone, bit for bit the same, which is how the reference gets its
+weights without taking anything the program holds. Nothing here imports
+the program; a method's `node` wraps a site's arrays in the program's
+node type.
 """
 from __future__ import annotations
 
-import functools
-import json
-
 import jax
 import jax.numpy as jnp
+
+import methods
 
 W4_QMAX = 7
 PACK_TILE = 256
@@ -32,39 +33,13 @@ PACK_TILE = 256
 _CODE_VAR = (15 ** 2 - 1) / 12.0
 
 
-def rank_for(k: int, n: int, fraction: float, multiple: int = 64) -> int:
-    """Rank of a (k, n) site under a uniform `rank_fraction` plan: the
-    fraction of min(k, n), floored to a multiple of 64 once
-    min(k, n) >= 256."""
-    full = min(k, n)
-    r = int(round(fraction * full))
-    if full >= 4 * multiple:
-        r = max(multiple, (r // multiple) * multiple)
-    return max(1, min(r, full))
-
-
 def packs(dim: int) -> bool:
     return dim % PACK_TILE == 0
 
 
-def sites(cfg: dict) -> dict:
-    """{site path: (K, N, stacked)} of every compressed linear."""
-    d, hd = cfg["d_model"], cfg["head_dim"]
-    h, hk, f = cfg["num_heads"], cfg["num_kv_heads"], cfg["d_ff"]
-    out = {"attn/wq": (d, h * hd, True), "attn/wk": (d, hk * hd, True),
-           "attn/wv": (d, hk * hd, True), "attn/wo": (h * hd, d, True),
-           "mlp/up": (d, f, True), "mlp/down": (f, d, True)}
-    if cfg["mlp_act"] == "swiglu":
-        out["mlp/gate"] = (d, f, True)
-    out["lm_head"] = (d, cfg["vocab_size"], False)
-    return out
-
-
-def site_shapes(cfg: dict, site: str) -> dict:
-    k, n, _ = sites(cfg)[site]
-    r = rank_for(k, n, cfg["plan"]["rank_fraction"])
-    return {"k": k, "n": n, "r": r, "w1_packed": packs(r),
-            "w2_packed": packs(n)}
+def shapes(plan: dict, k: int, n: int) -> dict:
+    """What the plan's method makes of a (K, N) site."""
+    return methods.load(plan["method"]).shapes(plan, k, n)
 
 
 def pack(codes):
@@ -88,8 +63,9 @@ def unpack(packed):
     return t.reshape(*lead, 2 * c)
 
 
-def _factor(key, rows, cols, scale_axis, fan_in, packed):
-    """One W4 factor: codes (rows, cols) and a scale per rank vector."""
+def factor(key, rows, cols, scale_axis, fan_in, packed):
+    """One W4 code matrix (rows, cols) and its f32 scales, one per column
+    (scale_axis 0) or per row (scale_axis 1)."""
     kc, ks = jax.random.split(key)
     codes = jax.random.randint(kc, (rows, cols), -W4_QMAX, W4_QMAX + 1,
                                jnp.int8)
@@ -106,12 +82,23 @@ def _site_key(key, site: str, layer: int | jax.Array):
 
 
 def site_arrays(key, shp: dict, site: str, layer):
-    """(w1 values, w1 scale, w2 values, w2 scale) of one layer of a site,
-    exactly as the program holds them (packed bytes where packed)."""
-    k1, k2 = jax.random.split(_site_key(key, site, layer))
-    v1, s1 = _factor(k1, shp["k"], shp["r"], 0, shp["k"], shp["w1_packed"])
-    v2, s2 = _factor(k2, shp["r"], shp["n"], 1, shp["r"], shp["w2_packed"])
-    return v1, s1, v2, s2
+    """One layer of a site exactly as the program holds it (packed bytes
+    where packed), as its plan method draws it."""
+    return methods.load(shp["method"]).arrays(_site_key(key, site, layer),
+                                              shp)
+
+
+def stacked_arrays(key, shp: dict, site: str, num_layers: int):
+    """`site_arrays` of every layer, with a leading layer axis, made one
+    layer at a time: the random bits of a whole quantized matrix in every
+    layer at once would not fit on the chip."""
+    return jax.lax.map(lambda layer: site_arrays(key, shp, site, layer),
+                       jnp.arange(num_layers))
+
+
+def node(arrays, shp: dict, plan: dict):
+    """One site's arrays in the program's node type."""
+    return methods.load(shp["method"]).node(arrays, shp, plan)
 
 
 def seed_key(seed: int):
@@ -132,55 +119,3 @@ def embed_table(key, cfg: dict):
     e = jax.random.normal(jax.random.fold_in(key, 7), (cfg["vocab_size"], d),
                           jnp.float32) * d ** -0.5
     return e.astype(jnp.dtype(cfg["dtype"]))
-
-
-def make_raw(key, cfg: dict) -> dict:
-    """Every weight as plain arrays: "embed", the norms, and per site
-    (v1, s1, v2, s2), with a leading layer axis on stacked sites."""
-    L = cfg["num_layers"]
-    out = {"embed": embed_table(key, cfg), "final_norm": norm_params(cfg),
-           "ln1": norm_params(cfg, (L,)), "ln2": norm_params(cfg, (L,))}
-    for site, (_, _, stacked) in sites(cfg).items():
-        shp = site_shapes(cfg, site)
-        if stacked:
-            out[site] = jax.vmap(
-                lambda l, s=site, p=shp: site_arrays(key, p, s, l))(
-                    jnp.arange(L))
-        else:
-            out[site] = site_arrays(key, shp, site, 0)
-    return out
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _make_raw_jit(key, cfg_json: str):
-    return make_raw(key, json.loads(cfg_json))
-
-
-def build_program_tree(seed: int, cfg: dict):
-    """The served parameter tree, made on the device in one jitted call."""
-    raw = _make_raw_jit(seed_key(seed), json.dumps(cfg, sort_keys=True))
-    return to_program_tree(raw, cfg)
-
-
-def to_program_tree(raw: dict, cfg: dict):
-    """Wrap the raw arrays in the program's LowRankQ / QuantizedTensor
-    nodes, in the tree layout its transformer reads."""
-    from repro.core.itera import LowRankQ
-    from repro.core.quant import QuantizedTensor
-
-    act_wl, wl = cfg["plan"]["act_wl"], cfg["plan"]["weight_wl"]
-
-    def node(site):
-        v1, s1, v2, s2 = raw[site]
-        shp = site_shapes(cfg, site)
-        return LowRankQ(
-            QuantizedTensor(v1, s1, wl, 0, shp["w1_packed"], act_wl),
-            QuantizedTensor(v2, s2, wl, 1, shp["w2_packed"], act_wl))
-
-    layers = {"attn": {k: node(f"attn/{k}") for k in ("wq", "wk", "wv",
-                                                        "wo")},
-              "ln1": raw["ln1"], "ln2": raw["ln2"],
-              "mlp": {k: node(f"mlp/{k}") for k in ("gate", "up", "down")
-                      if f"mlp/{k}" in raw}}
-    return {"embed": raw["embed"], "final_norm": raw["final_norm"],
-            "layers": layers, "lm_head": node("lm_head")}

@@ -1,6 +1,9 @@
 """Drive a whole benchmark run on the CPU at a tiny size, in a copy of the
 benchmark directory: the harness's look for a chip is skipped, and
-`fault` plants a defect in the served path underneath."""
+`fault` plants a defect in the served path underneath. A CPU trace holds
+no TPU plane, so a traced run reduces it to one fixed step of one second
+of device time: what such a run tests is the way from the window's rows
+through the family's work to the per-layer metrics."""
 import json
 import os
 import shutil
@@ -12,7 +15,7 @@ PERFBENCH = os.path.dirname(HERE)
 SRC = os.path.join(os.path.dirname(PERFBENCH), "src")
 
 TINY = {
-    "name": "tiny", "source": "test", "reduced": [],
+    "name": "tiny", "source": "test", "reduced": [], "family": "dense",
     "model": {"num_layers": 2, "d_model": 256, "num_heads": 4,
               "num_kv_heads": 2, "head_dim": 64, "d_ff": 512,
               "vocab_size": 512, "mlp_act": "swiglu", "norm": "rmsnorm",
@@ -24,6 +27,9 @@ TINY = {
                 "matmul_precision": "highest", "num_blocks": 33},
     "max_positions": 512,
 }
+TINY_QUANT = {**TINY, "name": "tiny-quant",
+              "plan": {"method": "quant", "weight_wl": 4, "act_wl": 8,
+                       "pack": True}}
 BURSTS = {"kind": "bursts", "burst_size": 4,
           "prompt": {"dist": "lognormal", "median": 16, "sigma": 0.8,
                      "min": 4, "max": 48},
@@ -48,6 +54,10 @@ def cpu_devices(chips):
     return jax.devices()[:1], next(iter(peaks.values()))
 
 bench.devices = cpu_devices
+if {trace!r}:
+    bench.devtrace.reduce = lambda pd, **_: bench.devtrace.Reduction(
+        window_s=1.0, busy_s=1.0, op_s={{}}, step_count=1,
+        step_device_s=1.0, gaps=[])
 fault = {fault!r}
 if fault:
     import jax.numpy as jnp
@@ -66,31 +76,39 @@ print(json.dumps(res))
 """
 
 
-def make_copy(root, traffic_name="tiny_bursts", mix=BURSTS, limit=0.5):
+def cfg(config=TINY) -> dict:
+    """What a family reads of a configuration, as `bench.Cell.cfg`."""
+    return {**config["model"], "plan": config["plan"],
+            "family": config["family"]}
+
+
+def make_copy(root, traffic_name="tiny_bursts", mix=BURSTS, limit=0.5,
+              config=TINY, metrics=("output_tok_s",)):
     """A checkout holding the benchmark plus one new traffic file, one new
-    configuration and one new BENCHMARK.json cell; nothing existing is
-    edited."""
+    configuration and one new BENCHMARK.json cell, which joins the
+    `workloads` of `metrics`; nothing existing is edited."""
     dst = os.path.join(root, "perfbench")
     shutil.copytree(PERFBENCH, dst, ignore=shutil.ignore_patterns(
         "__pycache__", "tests"))
-    cell = f"tiny.{traffic_name}"
+    name = config["name"]
+    cell = f"{name}.{traffic_name}"
     with open(os.path.join(dst, "traffic", f"{traffic_name}.json"), "w") as f:
         json.dump(mix, f)
-    with open(os.path.join(dst, "configs", "tiny.json"), "w") as f:
-        json.dump(TINY, f)
+    with open(os.path.join(dst, "configs", f"{name}.json"), "w") as f:
+        json.dump(config, f)
     with open(os.path.join(dst, "limits", f"{cell}.json"), "w") as f:
         json.dump({"max_gap": {"limit": limit}}, f)
     with open(os.path.join(os.path.dirname(PERFBENCH),
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
-    bench["configs"].append({"name": "tiny", "source": "test",
-                             "file": "perfbench/configs/tiny.json",
+    bench["configs"].append({"name": name, "source": "test",
+                             "file": f"perfbench/configs/{name}.json",
                              "reduced": [], "why": "test"})
-    bench["workloads"].append({"name": cell, "config": "tiny",
+    bench["workloads"].append({"name": cell, "config": name,
                                "traffic": traffic_name, "chips": 1,
                                "why": "test"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m and m["name"] in ("output_tok_s",):
+        if "workloads" in m and m["name"] in metrics:
             m["workloads"].append(cell)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)

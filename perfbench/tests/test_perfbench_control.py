@@ -22,21 +22,31 @@ def test_broken_served_path_is_not_correct(tmp_path, fault):
 
 def _greedy(cfg, seed, prompt, n):
     """n tokens the reference itself picks greedily after `prompt`."""
-    import reference
+    from families import dense
 
     seq = list(prompt)
     width = len(prompt) + n
     for _ in range(n):
         s = np.zeros(width, np.int32)       # one padded shape, one compile
         s[:len(seq)] = seq
-        h = reference.hidden_states(seed, cfg, [s])[0]
-        lg = np.asarray(reference.logits(seed, cfg, h))
+        h = dense.hidden_states(seed, cfg, [s])[0]
+        lg = np.asarray(dense.logits(seed, cfg, h))
         seq.append(int(lg[len(seq) - 1].argmax()))
     return np.asarray(seq[len(prompt):], np.int32)
 
 
 def test_control_reads_above_served_path():
-    cfg = {**cpu_cell.TINY["model"], "plan": cpu_cell.TINY["plan"]}
+    _control_reads_above(cpu_cell.cfg())
+
+
+def test_control_reads_above_served_path_quant():
+    """The quantization-only plan in bfloat16, as `phi3m-w4quant` runs
+    it, so the control is float8_e4m3fn."""
+    _control_reads_above({**cpu_cell.cfg(cpu_cell.TINY_QUANT),
+                          "dtype": "bfloat16"})
+
+
+def _control_reads_above(cfg):
     rng = np.random.default_rng(0)
     items = []
     for n_prompt in (24, 24):

@@ -1,13 +1,16 @@
 """The weights the benchmark makes from its seed have exactly the tree,
 shapes, dtypes and static aux that `compress_params` emits for the same
-uniform packed ITERA-W4 plan, and the reference regenerates them bit for
-bit, layer by layer."""
+uniform packed W4 plan, ITERA or quantization alone, and the reference
+regenerates them bit for bit, layer by layer."""
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import synth
+from families import dense
 
 SMOKE = [
     dict(num_layers=2, d_model=512, num_heads=4, num_kv_heads=2,
@@ -20,6 +23,10 @@ SMOKE = [
          norm="layernorm", dtype="float32",
          plan=dict(method="itera", weight_wl=4, act_wl=8,
                    rank_fraction=0.5, pack=True)),
+    dict(num_layers=2, d_model=512, num_heads=4, num_kv_heads=2,
+         head_dim=128, d_ff=1024, vocab_size=1000, mlp_act="swiglu",
+         norm="rmsnorm", dtype="bfloat16",
+         plan=dict(method="quant", weight_wl=4, act_wl=8, pack=True)),
 ]
 
 
@@ -31,18 +38,20 @@ def _model_config(cfg):
     return ModelConfig(name="smoke", **{k: cfg[k] for k in keys})
 
 
-@pytest.mark.parametrize("cfg", SMOKE, ids=["swiglu-bf16", "gelu-f32"])
+@pytest.mark.parametrize("cfg", SMOKE, ids=["swiglu-bf16", "gelu-f32",
+                                            "quant-swiglu-bf16"])
 def test_tree_matches_compress_params(cfg):
     from repro.api.plan import CompressionPlan
     from repro.core.compress import compress_params
     from repro.models import transformer as tfm
 
-    dense = tfm.init_params(jax.random.PRNGKey(0), _model_config(cfg))
+    params = tfm.init_params(jax.random.PRNGKey(0), _model_config(cfg))
+    p = cfg["plan"]
     plan = CompressionPlan.uniform(
-        dense, method="itera", weight_wl=4, act_wl=8,
-        rank_fraction=cfg["plan"]["rank_fraction"], power_iters=1)
-    want, _ = compress_params(dense, plan)
-    got = synth.build_program_tree(3_000_000_017, cfg)
+        params, method=p["method"], weight_wl=4, act_wl=8,
+        rank_fraction=p.get("rank_fraction", 0.5), power_iters=1)
+    want, _ = compress_params(params, plan)
+    got = dense.program_tree(3_000_000_017, cfg)
     assert (jax.tree_util.tree_structure(got)
             == jax.tree_util.tree_structure(want))
     for a, b in zip(jax.tree_util.tree_leaves(got),
@@ -60,19 +69,33 @@ def test_pack_matches_program_layout():
     np.testing.assert_array_equal(unpack_int4(synth.pack(codes)), codes)
 
 
-def test_site_regenerates_layer_slices():
-    cfg = SMOKE[0]
+def _regenerates_layer_slices(cfg, n_arrays):
+    """Every layer is made one at a time (`lax.map`), and one layer made
+    alone reads the same bits, both compiled as a run compiles them: the
+    tree in one jitted call, the reference's layer in another (a scale
+    divided by a constant rounds alike only where both are)."""
     key = synth.seed_key(5)
-    raw = synth.make_raw(key, cfg)
+    raw = dense._make_raw_jit(key, json.dumps(cfg, sort_keys=True))
     for site in ("attn/wk", "mlp/down"):
-        shp = synth.site_shapes(cfg, site)
-        one = synth.site_arrays(key, shp, site, 1)
+        shp = dense.site_shapes(cfg, site)
+        one = jax.jit(lambda k, layer, s=site, p=shp: synth.site_arrays(
+            k, p, s, layer))(key, 1)
+        assert len(one) == len(raw[site]) == n_arrays
         for a, b in zip(one, raw[site]):
             np.testing.assert_array_equal(a, b[1])
 
 
+def test_site_regenerates_layer_slices():
+    _regenerates_layer_slices(SMOKE[0], 4)
+
+
+def test_quant_site_regenerates_layer_slices():
+    _regenerates_layer_slices(SMOKE[2], 2)
+
+
 def test_seed_changes_weights():
     cfg = SMOKE[1]
-    a = synth.make_raw(synth.seed_key(1), cfg)["attn/wq"][0]
-    b = synth.make_raw(synth.seed_key(2), cfg)["attn/wq"][0]
+    a = dense.make_raw(synth.seed_key(1), cfg)["attn/wq"][0]
+    b = dense.make_raw(synth.seed_key(2), cfg)["attn/wq"][0]
     assert not np.array_equal(a, b)
+

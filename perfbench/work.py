@@ -1,7 +1,8 @@
 """Required work of the served step, counted from the plan's shapes and
 from valid tokens only: never the padded (rows, span) rectangle the step
 computes, and never a kernel's own byte model. The same counts hold
-whatever implements a kernel.
+whatever implements a kernel. A model family (`families/<name>.py`)
+gives its work by kernel from the helpers here.
 
 Per request with a P-token prompt and n served tokens, the step runs the
 linears on P + n - 1 rows (the prompt, then each served token but the
@@ -15,65 +16,89 @@ from __future__ import annotations
 
 import dataclasses
 
-import synth
+import numpy as np
 
-_ITEMSIZE = {"bfloat16": 2, "float32": 4, "float16": 2}
+import families
+import methods
+
+ITEMSIZE = {"bfloat16": 2, "float32": 4, "float16": 2}
+
+
+@dataclasses.dataclass
+class Rows:
+    """One request's rows in a window: `ids` its whole sequence (prompt,
+    then served tokens), `positions` of the rows the window processed
+    (every prompt position, each served token fed back as a decode
+    input), and `steps` the serving step each of them ran in, numbered
+    from 0 in the window's order. A step is known by the host time at
+    which its tokens were read back, so a prompt's rows are all placed in
+    the step that ran its last chunk."""
+
+    ids: np.ndarray
+    positions: list
+    steps: list
 
 
 @dataclasses.dataclass
 class Tokens:
     """Valid rows a window processed: `prompt_lens` of the prompts it
     prefilled and `decode_positions` of the served tokens fed back as
-    decode inputs; `emitted` served tokens (output-head rows)."""
+    decode inputs; `emitted` served tokens (output-head rows). `requests`
+    holds the same rows by request (`Rows`), with token ids and steps,
+    for work that depends on what the rows hold, such as routing."""
 
     prompt_lens: list
     decode_positions: list
     emitted: int
+    requests: list = dataclasses.field(default_factory=list)
 
     @property
     def rows(self) -> int:
         return sum(self.prompt_lens) + len(self.decode_positions)
 
 
-def lowrank_weight_bytes(shp: dict) -> int:
-    k, r, n = shp["k"], shp["r"], shp["n"]
-    w1 = k * r // 2 if shp["w1_packed"] else k * r
-    w2 = r * n // 2 if shp["w2_packed"] else r * n
-    return w1 + w2 + 4 * 2 * r
+def linears(cfg: dict, sites, steps: int) -> dict:
+    """Int8 operations and HBM bytes every linear kernel call of the
+    window needs, by the kernel each site's plan method names: each
+    site's codes read once per step, each valid row read once (int8 codes
+    plus a row scale) and written once. `sites` holds (shapes, layers,
+    rows) per site: `synth.shapes`, the layers that hold it and the valid
+    rows each of them takes."""
+    out_b = ITEMSIZE[cfg["dtype"]]
+    out = {}
+    for shp, layers, rows in sites:
+        m = methods.load(shp["method"])
+        w = out.setdefault(m.KERNEL,
+                           {"ops": 0, "bytes": 0, "peak": "int8_ops"})
+        w["ops"] += layers * 2 * rows * m.row_macs(shp)
+        w["bytes"] += layers * (steps * m.weight_bytes(shp)
+                                + rows * (shp["k"] + 4 + shp["n"] * out_b))
+    return out
 
 
-def lowrank(cfg: dict, tok: Tokens, steps: int) -> dict:
-    """Int8 operations and HBM bytes every `lowrank_qmm` call of the
-    window needs: each site's factors read once per step, each valid row
-    read once (int8 codes plus a row scale) and written once."""
-    out_b = _ITEMSIZE[cfg["dtype"]]
-    ops = byt = 0
-    for site, (_, _, stacked) in synth.sites(cfg).items():
-        shp = synth.site_shapes(cfg, site)
-        layers = cfg["num_layers"] if stacked else 1
-        rows = tok.rows if stacked else tok.emitted
-        ops += layers * 2 * rows * shp["r"] * (shp["k"] + shp["n"])
-        byt += layers * (steps * lowrank_weight_bytes(shp)
-                         + rows * (shp["k"] + 4 + shp["n"] * out_b))
-    return {"ops": ops, "bytes": byt}
-
-
-def attention(cfg: dict, tok: Tokens) -> dict:
-    """Float operations and HBM bytes of `paged_attention` over the
-    window: QK and PV over each row's visible positions; K and V of every
-    position read once per decode row, each prompt's positions read at
-    least once; queries read and outputs written once."""
-    h, hk, hd = cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"]
-    b = _ITEMSIZE[cfg["dtype"]]
-    visible = sum(p * (p + 1) // 2 for p in tok.prompt_lens) + sum(
-        q + 1 for q in tok.decode_positions)
-    read = sum(tok.prompt_lens) + sum(q + 1 for q in tok.decode_positions)
-    L = cfg["num_layers"]
-    ops = L * 4 * h * hd * visible
-    byt = L * (2 * hk * hd * b * read + 2 * h * hd * b * tok.rows)
-    return {"ops": ops, "bytes": byt}
+def required(ctx) -> dict:
+    """{kernel: {"ops", "bytes", "peak"}} of the traced window, from the
+    configuration's family, which gets the window's rows and the seed its
+    weights were made from."""
+    cfg = ctx["cfg"]
+    return families.load(cfg["family"]).work(
+        cfg, ctx["trace_tokens"], ctx["trace"].step_count, ctx["seed"])
 
 
 def least_seconds(ops: float, byt: float, op_peak: float,
                   bw: float) -> float:
     return max(ops / op_peak, byt / bw)
+
+
+def roofline(ctx, kernel: str):
+    """Least time of the traced window's required `kernel` work at the
+    chip's peak for it or HBM bandwidth, whichever binds, over the device
+    time of the events of that name, in %; None where it did not run."""
+    t = ctx["trace"].kernel_s(kernel)
+    got = required(ctx).get(kernel)
+    if not t or got is None:
+        return None
+    pk = ctx["peaks"]
+    least = least_seconds(got["ops"], got["bytes"], pk[got["peak"]],
+                          pk["hbm_bytes_per_s"])
+    return 100.0 * least / t
