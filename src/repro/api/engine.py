@@ -418,7 +418,7 @@ class InferenceEngine:
         # overhead, not FLOPs. One variant traces per static
         # (any-row-samples, any-stop-criteria) pair; the (False, False)
         # variant is the bare greedy step (no sort, no PRNG, no ring).
-        self._unified_cache: dict[tuple[bool, bool], object] = {}
+        self._unified_cache: dict[tuple, object] = {}
         if self._tp:
             from repro.launch import sharding as shd
 
@@ -438,10 +438,15 @@ class InferenceEngine:
         self._cow_copy = jax.jit(kvblocks.copy_block)
         tracing.trace_collections()
 
-    def _unified_fn(self, sample: bool, stop: bool):
+    def _unified_fn(self, sample: bool, stop: bool,
+                    budget: int | None = None):
         """The jitted fused serving step for one static (sample, stop)
-        pair, traced on first use and cached for the engine's lifetime."""
-        fn = self._unified_cache.get((sample, stop))
+        pair and token budget, traced on first use and cached for the
+        engine's lifetime. The budget sizes a MoE step's routing slots
+        (`moe.moe_routed`); a dense step does not read it, so one
+        program serves every budget there (`transformer.step_budget`)."""
+        budget = tfm.step_budget(self.cfg, budget)
+        fn = self._unified_cache.get((sample, stop, budget))
         if fn is not None:
             return fn
         cfg = self.cfg
@@ -471,7 +476,7 @@ class InferenceEngine:
                 with shardctx.tp_axis("model"):
                     return tfm.serve_step(p, pool, bt, buf, prev, recent,
                                           stops, lcfg, sample=sample,
-                                          stop=stop)
+                                          stop=stop, token_budget=budget)
 
             fn = jax.jit(shardctx.tp_shard_map(
                 serving_step, self.mesh,
@@ -480,10 +485,11 @@ class InferenceEngine:
         else:
             def serving_step(p, pool, bt, buf, prev, recent, stops):
                 return tfm.serve_step(p, pool, bt, buf, prev, recent, stops,
-                                      cfg, sample=sample, stop=stop)
+                                      cfg, sample=sample, stop=stop,
+                                      token_budget=budget)
 
             fn = jax.jit(serving_step)
-        self._unified_cache[(sample, stop)] = fn
+        self._unified_cache[(sample, stop, budget)] = fn
         return fn
 
     @staticmethod
@@ -815,11 +821,15 @@ class InferenceEngine:
         def consume(emits, toks_dev, fin_dev):
             """Read back one step's sampled tokens + finished mask
             (blocks until the device finishes that step) and credit
-            them to requests."""
+            them to requests. Returns that step's `experts_hit` while a
+            profiler session records and the tokens carry it in a second
+            column (`transformer.step_token_cols`), else 0."""
             with tracing.span("serve.consume"):
                 with tracing.span("serve.readback"):
                     vals = np.asarray(toks_dev)
                     fins = None if fin_dev is None else np.asarray(fin_dev)
+                hits = (int(vals[0, 1]) if vals.shape[1] > 1
+                        and tracing.active() else 0)
                 now = time.time()
                 for rid, r in emits:
                     if rid in stopped:
@@ -839,6 +849,7 @@ class InferenceEngine:
                                             token=out_vals[rid][-1],
                                             index=len(out_vals[rid]) - 1,
                                             time=now, final=done))
+            return hits
 
         with ctx:
             if ctl is not None:
@@ -851,13 +862,14 @@ class InferenceEngine:
                 sched_done = True
             else:
                 sched_done = False
-            step_fn = self._unified_fn(do_sample, do_stop)
+            step_fn = self._unified_fn(do_sample, do_stop, budget)
             tables_dev = None       # device-safe copy, refreshed on change
             stops_dev = None        # ditto, for the stop-sequence buffer
             stop_buf = np.full((cap, n_stops, stop_len), -1, np.int32)
             no_stops = jnp.zeros((cap, 1, 1), jnp.int32)  # stop=False dummy
             inflight = collections.deque()  # (emits, toks, fin), oldest
-            prev_toks = jnp.zeros((cap, 1), jnp.int32)
+            prev_toks = jnp.zeros((cap, tfm.step_token_cols(self.cfg)),
+                                  jnp.int32)
             recent = jnp.zeros((cap, stop_len), jnp.int32)
             while not sched_done and sched.has_work():
                 with tracing.span("serve.step") as step_span:
@@ -967,8 +979,9 @@ class InferenceEngine:
                     # through the host's scheduling + readback work)
                     inflight.append((emits, toks_dev,
                                      fin_dev if do_stop else None))
+                    hits = 0
                     if len(inflight) > 2:
-                        consume(*inflight.popleft())
+                        hits += consume(*inflight.popleft())
                     if stopped:
                         # rows whose stop fired (discovered at consume):
                         # retire them before the next schedule so the
@@ -983,7 +996,10 @@ class InferenceEngine:
                         # the last dispatch: read back what is in flight
                         with tracing.span("serve.drain"):
                             while inflight:
-                                consume(*inflight.popleft())
+                                hits += consume(*inflight.popleft())
+                    if tracing.active() and toks_dev.shape[1] > 1:
+                        # experts hit by the steps read back in this one
+                        step_span.set_metadata(experts_hit=hits)
             # stops discovered in the final drain: the rows already
             # finished by count, but late-stopped outputs stay truncated
             if not sched_done:

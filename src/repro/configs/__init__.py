@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 
-from repro.configs.base import ModelConfig, MoEConfig, SSMConfig
+from repro.configs.base import ModelConfig, MoEConfig, RopeScaling, SSMConfig
 
 _MODULES = {
     "mixtral-8x22b": "repro.configs.mixtral_8x22b",
@@ -28,9 +28,12 @@ _MODULES = {
     "chameleon-34b": "repro.configs.chameleon_34b",
     "falcon-mamba-7b": "repro.configs.falcon_mamba_7b",
     "opus-mt": "repro.configs.opus_mt",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
-ARCH_IDS = [k for k in _MODULES if k != "opus-mt"]
+# the dry-run architectures; opus-mt (the paper's proxy) and
+# deepseek-v2-lite (latent attention: serve() only) are served, not dry-run
+ARCH_IDS = [k for k in _MODULES if k not in ("opus-mt", "deepseek-v2-lite")]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +80,7 @@ def cells(include_skipped: bool = False):
 
 
 __all__ = [
-    "ModelConfig", "MoEConfig", "SSMConfig", "ShapeSpec", "SHAPES",
+    "ModelConfig", "MoEConfig", "RopeScaling", "SSMConfig", "ShapeSpec",
+    "SHAPES",
     "ARCH_IDS", "get_config", "shape_applicable", "cells",
 ]

@@ -16,8 +16,39 @@ class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
     num_shared: int = 0            # shared (always-on) experts, deepseek-style
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training/rectangular dispatch only
     router_jitter: float = 0.0
+    d_expert: Optional[int] = None  # one expert's width (default d_ff)
+    first_dense: int = 0           # leading layers with a dense d_ff MLP
+    norm_topk_prob: bool = True    # renormalize the top-k gate weights
+    routed_scaling_factor: float = 1.0
+
+    def expert_width(self, d_ff: int) -> int:
+        return self.d_expert or d_ff
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (DeepSeek-V2): frequencies past the
+    `original_max_position_embeddings` correction range are divided by
+    `factor`, and attention logits are scaled by mscale(mscale_all_dim)^2.
+
+    DeepSeek-V2 also multiplies cos and sin by mscale(mscale) /
+    mscale(mscale_all_dim); the served rotary applies no such factor, so
+    a configuration where it is not 1 is refused."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if self.factor > 1 and self.mscale != self.mscale_all_dim:
+            raise ValueError(
+                f"YaRN mscale {self.mscale} != mscale_all_dim "
+                f"{self.mscale_all_dim} scales cos/sin, which the served "
+                f"rotary does not apply")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +81,7 @@ class ModelConfig:
     logit_softcap: float = 0.0              # Gemma2 attn soft-capping
     final_softcap: float = 0.0              # Gemma2 final-logit soft-capping
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None  # YaRN (DeepSeek-V2)
     rotary_pct: float = 1.0                 # StableLM partial rotary
     pos_emb: str = "rope"                   # rope | sinusoidal | none
     attn_impl: str = "auto"                 # auto | full | chunked
@@ -62,6 +94,15 @@ class ModelConfig:
     # would bloat the HLO for tests while the TPU path gets the O(ctx)
     # streaming win).
     paged_attn_impl: str = "auto"           # auto | kernel | ref
+
+    # multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0 caches
+    # one (kv_lora_rank + qk_rope_head_dim)-wide latent per token and
+    # layer instead of per-head K and V; queries are qk_nope_head_dim +
+    # qk_rope_head_dim wide per head, values v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MLP flavor
     mlp_act: str = "swiglu"                 # swiglu | relu2 | gelu | geglu
@@ -92,6 +133,15 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def first_dense(self) -> int:
+        """Leading layers that run a dense MLP in a MoE layout."""
+        return self.moe.first_dense if self.layout == "moe" else 0
+
+    @property
     def is_attention_free(self) -> bool:
         return self.layout == "ssm"
 
@@ -111,13 +161,23 @@ class ModelConfig:
         hd = self.head_dim
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd \
             + self.num_heads * hd * d
+        if self.is_mla:
+            c, r = self.kv_lora_rank, self.qk_rope_head_dim
+            qd = self.qk_nope_head_dim + r
+            attn = (d * self.num_heads * qd + d * (c + r)
+                    + c * self.num_heads * (self.qk_nope_head_dim
+                                            + self.v_head_dim)
+                    + self.num_heads * self.v_head_dim * d)
         mlp_mats = 3 if self.mlp_act in ("swiglu", "geglu") else 2
         mlp = mlp_mats * d * self.d_ff
         if self.layout == "dense":
             n += L * (attn + mlp)
         elif self.layout == "moe":
             e = self.moe.num_experts + self.moe.num_shared
-            n += L * (attn + e * mlp + d * self.moe.num_experts)
+            emlp = mlp_mats * d * self.moe.expert_width(self.d_ff)
+            fd = self.first_dense
+            n += L * attn + fd * mlp
+            n += (L - fd) * (e * emlp + d * self.moe.num_experts)
         elif self.layout == "ssm":
             di = d * self.ssm.expand
             dtr = self.ssm.dt_rank or d // 16
@@ -139,7 +199,8 @@ class ModelConfig:
             return self.param_count()
         d, L = self.d_model, self.num_layers
         mlp_mats = 3 if self.mlp_act in ("swiglu", "geglu") else 2
-        mlp = mlp_mats * d * self.d_ff
+        mlp = mlp_mats * d * self.moe.expert_width(self.d_ff)
         e_all = self.moe.num_experts + self.moe.num_shared
         e_act = self.moe.top_k + self.moe.num_shared
-        return self.param_count() - L * (e_all - e_act) * mlp
+        return self.param_count() - (L - self.first_dense) * (
+            e_all - e_act) * mlp

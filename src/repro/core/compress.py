@@ -60,7 +60,8 @@ class CompressionConfig:
     ranks: dict | None = None          # per-layer override (path -> rank), e.g. from SRA
     min_rank: int = 1
     include: str = r".*"               # regex over pytree paths
-    exclude: str = r"(embed|router|norm|scale|bias|ln|pos)"
+    # w_uk|w_uv: latent attention's absorbed kv_b_proj halves stay plain
+    exclude: str = r"(embed|router|norm|scale|bias|ln|pos|w_uk|w_uv)"
     min_dim: int = 32                  # skip tiny matrices (router heads etc.)
     power_iters: int = 24
 

@@ -32,6 +32,7 @@ from repro.core.itera import LowRankQ
 from repro.core.quant import (
     QuantizedTensor, packed_pad_ok, qmax, unpack_int4,
 )
+from repro.kernels import lowrank_gmm as _gmm
 from repro.kernels import lowrank_qmm as _lr
 from repro.kernels import quant_matmul as _qm
 from repro.kernels import ref as _ref
@@ -257,6 +258,101 @@ def lrmm(
         w1_packed=w1_packed, w2_packed=w2_packed, act_qmax=act_qm,
     )[:m, :n]
     return y.reshape(*lead, n)
+
+
+GMM_TILE = 128      # rows per lowrank_gmm tile
+
+
+@functools.partial(
+    jax.jit, static_argnames=("use_kernel", "interpret", "out_dtype"))
+def lrgmm(
+    x: jax.Array,
+    lr: LowRankQ,
+    offsets: jax.Array,
+    *,
+    use_kernel: bool = True,
+    interpret: bool | None = None,
+    out_dtype=jnp.float32,
+) -> jax.Array:
+    """Grouped ITERA linear: row i of x (M, K), sorted by expert, through
+    expert e's cascade where offsets[e] <= i < offsets[e+1]; `lr` holds
+    every expert's factors with a leading expert axis. Rows at or past
+    offsets[E] come back unspecified (zeros on the reference path).
+
+    use_kernel=False is the jnp oracle: every expert's `lowrank_qmm_ref`
+    over all rows, each row kept from its own expert (E x the compute;
+    the kernel's arithmetic bit for bit)."""
+    if interpret is None:
+        interpret = not on_tpu()
+    e, k, r = lr.w1.shape
+    n = lr.w2.shape[-1]
+    m = x.shape[0]
+    act_qm = qmax(lr.act_wl)
+    xq, sx = quantize_acts(x, act_qm)
+    s1 = lr.w1.scale.reshape(e, 1, r)
+    s2 = lr.w2.scale.reshape(e, r, 1)
+    rows = jnp.arange(m)[:, None]
+
+    if not use_kernel:
+        w1v = unpack_int4(lr.w1.values) if lr.w1.packed else lr.w1.values
+        w2v = unpack_int4(lr.w2.values) if lr.w2.packed else lr.w2.values
+
+        def one(y, ex):
+            w1, a1, w2, a2, lo, hi = ex
+            ye = _ref.lowrank_qmm_ref(xq, sx, w1, a1, w2, a2, act_qm)
+            return jnp.where((rows >= lo) & (rows < hi), ye, y), None
+
+        y, _ = jax.lax.scan(one, jnp.zeros((m, n), jnp.float32),
+                            (w1v, s1, w2v, s2, offsets[:-1], offsets[1:]))
+        return y.astype(out_dtype)
+
+    w1_packed, w1v = lr.w1.packed, lr.w1.values
+    if w1_packed and not packed_pad_ok(r):
+        w1v, w1_packed = unpack_int4(w1v), False
+    w2_packed, w2v = lr.w2.packed, lr.w2.values
+    if w2_packed and not packed_pad_ok(n):
+        w2v, w2_packed = unpack_int4(w2v), False
+    rp = _round_up(r, 256 if w1_packed else 128)
+    kp, np_ = _round_up(k, 128), _round_up(n, 256 if w2_packed else 128)
+    mp = _round_up(m, GMM_TILE)
+
+    def pad3(a, d1, d2, value=0):
+        return jnp.pad(a, ((0, 0), (0, d1 - a.shape[1]),
+                           (0, d2 - a.shape[2])), constant_values=value)
+
+    y = _gmm.lowrank_gmm(
+        _pad2(xq, mp, kp), _pad2(sx, mp, 1),
+        pad3(w1v, kp, rp // 2 if w1_packed else rp),
+        pad3(s1, 1, rp, 1.0),
+        pad3(w2v, rp, np_ // 2 if w2_packed else np_),
+        pad3(s2, rp, 1, 1.0),
+        offsets, tm=GMM_TILE, out_dtype=out_dtype, interpret=interpret,
+        w1_packed=w1_packed, w2_packed=w2_packed, act_qmax=act_qm,
+    )
+    return y[:m, :n]
+
+
+def qgmm(x: jax.Array, w: QuantizedTensor, offsets: jax.Array, *,
+         out_dtype=jnp.float32) -> jax.Array:
+    """Grouped WxAy linear: row i of x (M, K), sorted by expert, through
+    expert e's codes where offsets[e] <= i < offsets[e+1]; `w` holds
+    every expert's codes (E, K, N) with per-column scales (E, 1, N).
+    `qmm`'s reference arithmetic (per-row activation codes, an integer
+    product, both scales) as one grouped product, so the work follows
+    the rows and not E x M; rows at or past offsets[E] come back zero."""
+    e = offsets.shape[0] - 1
+    m, n = x.shape[0], w.shape[-1]
+    xq, sx = quantize_acts(x, qmax(w.act_wl))
+    wv = unpack_int4(w.values) if w.packed else w.values
+    # integer codes are exact at the default precision, and the grouped
+    # product's TPU kernel refuses "highest"
+    acc = jax.lax.ragged_dot(xq, wv.astype(jnp.int8), jnp.diff(offsets),
+                             precision=jax.lax.Precision.DEFAULT,
+                             preferred_element_type=jnp.int32)
+    grp = jnp.minimum(jnp.searchsorted(offsets[1:], jnp.arange(m),
+                                       side="right"), e - 1)
+    sw = w.scale.reshape(e, n)[grp]
+    return (acc.astype(jnp.float32) * sx * sw).astype(out_dtype)
 
 
 def qmm_hbm_bytes(m: int, w: QuantizedTensor,

@@ -62,6 +62,11 @@ chip streams only its own heads' KV blocks. Attention is head-local,
 so no collective touches the kernel; the single psum per attention
 boundary happens outside, after the wo projection
 (models.transformer.unified_step).
+
+Multi-head latent attention has its own mode under the same kernel name,
+`latent_paged_attention`: one latent entry per token in the pool, every
+query head scored against it, its first `kv_lora_rank` columns the
+value (see its docstring).
 """
 from __future__ import annotations
 
@@ -307,6 +312,170 @@ def paged_attention(q, pool, block_table, ctx_lens, q_lens, *,
       *leaves)
     return (out.reshape(b, hk, w, g, dh).transpose(0, 2, 1, 3, 4)
             .reshape(b, w, h, dh))
+
+
+# ------------------------------------------------------------ latent mode --
+def latent_vmem_bytes(tq: int, dp: int, vw: int, p: int, bs: int, *,
+                      itemsize: int) -> int:
+    """Scoped VMEM one latent-mode program needs, counted like
+    `vmem_bytes`: double-buffered q and output tiles, the f32 (m, l, acc)
+    scratch, one two-slot chunk buffer of latent entries, and the f32
+    score tiles and entry tile of one chunk."""
+    io = 2 * (tile_bytes(tq, dp, itemsize) + tile_bytes(tq, vw, itemsize))
+    state = tile_bytes(tq, vw, 4) + 2 * tile_bytes(tq, 1, 4)
+    slots = 2 * p * tile_bytes(bs, dp, itemsize)
+    temps = 3 * tile_bytes(tq, p * bs, 4) + tile_bytes(p * bs, dp, 4)
+    return io + state + slots + temps
+
+
+def latent_tile(w: int, g: int, dp: int, vw: int, bs: int, table_width: int,
+                *, itemsize: int) -> tuple[int, int]:
+    """(P, wt): table entries per chunk, and the query positions per
+    program — the most positions (a power-of-two divisor of the span
+    width W) whose W*G-row tile fits the VMEM budget, in whole 8-row
+    sublane tiles unless it is the whole span."""
+    p = chunk_blocks(bs, table_width)
+    wt = w
+    while wt > 1 and (latent_vmem_bytes(wt * g, dp, vw, p, bs,
+                                        itemsize=itemsize) > VMEM_BUDGET
+                      or (wt * g) % 8):
+        wt //= 2
+    if (wt * g) % 8 and wt != w:
+        wt = w
+    return p, wt
+
+
+def _latent_kernel(bt_ref, ctx_ref, ql_ref, q_ref, hbm, o_ref, buf, sem,
+                   m_ref, l_ref, acc_ref, *, bs, g, p, wt, mb, scale, vw):
+    r, t = pl.program_id(0), pl.program_id(1)
+    ctx, ql = ctx_ref[r], ql_ref[r]
+    q0 = t * wt                     # the tile's first span position
+
+    @pl.when(q0 < ql)               # a tile with no valid query is skipped
+    def _run():
+        # keys visible to the tile's last valid query, in whole blocks
+        nb = jnp.minimum((ctx + jnp.minimum(q0 + wt, ql) + bs - 1) // bs, mb)
+        n_chunks = (nb + p - 1) // p
+
+        def each_block(c, slot, op):
+            def body(i, carry):
+                op(pltpu.make_async_copy(
+                    hbm.at[bt_ref[r, c * p + i], 0], buf.at[slot, i],
+                    sem.at[slot]))
+                return carry
+
+            jax.lax.fori_loop(0, jnp.minimum(p, nb - c * p), body, 0)
+
+        m_ref[...] = jnp.full_like(m_ref, NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        each_block(0, 0, lambda cp: cp.start())
+        tq = q_ref.shape[1]
+        pb = p * bs
+
+        def chunk(c, carry):
+            slot = c % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _next():
+                each_block(c + 1, 1 - slot, lambda cp: cp.start())
+
+            each_block(c, slot, lambda cp: cp.wait())
+            kpos = c * pb + jax.lax.broadcasted_iota(jnp.int32, (tq, pb), 1)
+            qpos = (ctx + q0
+                    + jax.lax.broadcasted_iota(jnp.int32, (tq, pb), 0) // g)
+            live = (kpos <= qpos) & (kpos < nb * bs)
+            filled = (c * pb + jax.lax.broadcasted_iota(jnp.int32, (pb, 1), 0)
+                      < nb * bs)
+            q = q_ref[0]                                   # (tq, Dp)
+            kv = buf[slot].reshape(pb, q.shape[-1])        # (P*bs, Dp)
+            s = jax.lax.dot_general(
+                q, kv, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live, s, NEG)
+            v = jnp.where(filled, kv[:, :vw], 0).astype(kv.dtype)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            pr = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=-1,
+                                                      keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                pr.astype(v.dtype), v,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, n_chunks, chunk, 0)
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "v_width", "interpret"))
+def latent_paged_attention(q, latent, block_table, ctx_lens, q_lens, *,
+                           scale: float, v_width: int,
+                           interpret: bool = False):
+    """The latent (MLA) mode of the kernel, under the same kernel name.
+
+    q: (B, W, H, Dp) absorbed queries [q_nope @ W_UK^T, rope(q_pe), 0
+    pad]; latent: ONE layer's pool leaf (NB, 1, bs, Dp), already holding
+    this step's entries; block_table (B, MB); ctx_lens / q_lens (B,).
+    Every query head shares the one latent "head" (G = H). Each valid
+    block is DMA'd once per (row, query tile) and serves as K whole and
+    as V by its first `v_width` columns — no second copy. The grid is
+    (B, W // wt): a program takes wt span positions of one row (wt*H
+    query rows, `latent_tile`), walks the row's valid blocks up to its
+    last position's context in chunks of P entries (double-buffered, as
+    the dense mode), and runs online softmax in f32. Programs whose
+    positions are all past q_lens[r] (decode rows in a wide span, idle
+    rows) compute nothing and fetch nothing new (their q and output
+    block indices repeat the row's last valid tile), so their outputs
+    are unspecified; the caller reads positions [:q_lens[r]] only.
+
+    Returns (B, W, H, v_width) in q.dtype.
+    """
+    b, w, h, dp = q.shape
+    _, _, bs, _ = latent.shape
+    mb = block_table.shape[1]
+    p, wt = latent_tile(w, h, dp, v_width, bs, mb,
+                        itemsize=q.dtype.itemsize)
+    tq = wt * h
+    qh = q.reshape(b, w * h, dp)               # position major, head minor
+
+    def q_map(r, t, bt, ctx, ql):
+        last = jnp.maximum((ql[r] + wt - 1) // wt - 1, 0)
+        return (r, jnp.minimum(t, last), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,          # block_table, ctx_lens, q_lens
+        grid=(b, w // wt),
+        in_specs=[pl.BlockSpec((1, tq, dp), q_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, tq, v_width), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, p, bs, dp), latent.dtype),   # two chunk slots
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((tq, 1), jnp.float32),           # running max m
+            pltpu.VMEM((tq, 1), jnp.float32),           # running denom l
+            pltpu.VMEM((tq, v_width), jnp.float32),     # running numerator
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, g=h, p=p, wt=wt, mb=mb,
+                          scale=scale, vw=v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, w * h, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="paged_attention",
+    )(block_table.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      q_lens.astype(jnp.int32), qh, latent)
+    return out.reshape(b, w, h, v_width)
 
 
 # ------------------------------------------------------------- byte model --

@@ -25,7 +25,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.models.layers import apply_linear, apply_rope, softcap
+from repro.models.layers import (
+    apply_linear, apply_rope, rmsnorm, rotate_pairs, softcap, yarn_inv_freq,
+    yarn_mscale,
+)
 
 NEG = -2.3819763e38  # large negative for masking in f32
 
@@ -40,6 +43,46 @@ def attn_init(key, cfg, dtype):
         "wv": jax.random.normal(ks[2], (d, hk * hd), dtype) * s,
         "wo": jax.random.normal(ks[3], (h * hd, d), dtype) * (h * hd) ** -0.5,
     }
+
+
+def mla_init(key, cfg, dtype):
+    """Multi-head latent attention (DeepSeek-V2, `q_lora_rank` null):
+    wq (d, H*(dn+dr)); wkv_a (d, C+dr) -> the latent c (C, then
+    `kv_norm`) and one rope key shared by every head; per-head
+    w_uk (H, dn, C) and w_uv (H, C, dv), the two halves of kv_b_proj,
+    kept as plain arrays for absorption; wo (H*dv, d)."""
+    d, h = cfg.d_model, cfg.num_heads
+    dn, dr, c, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.kv_lora_rank, cfg.v_head_dim)
+    ks = jax.random.split(key, 5)
+    s = d ** -0.5
+    return {
+        "wq": jax.random.normal(ks[0], (d, h * (dn + dr)), dtype) * s,
+        "wkv_a": jax.random.normal(ks[1], (d, c + dr), dtype) * s,
+        "kv_norm": {"gamma": jnp.zeros((c,), dtype)},
+        "w_uk": jax.random.normal(ks[2], (h, dn, c), dtype) * c ** -0.5,
+        "w_uv": jax.random.normal(ks[3], (h, c, dv), dtype) * c ** -0.5,
+        "wo": jax.random.normal(ks[4], (h * dv, d), dtype) * (h * dv) ** -0.5,
+    }
+
+
+def mla_rope_freqs(cfg):
+    """Rotary frequencies of the rope dims: YaRN where configured."""
+    dr = cfg.qk_rope_head_dim
+    if cfg.rope_scaling is not None:
+        return yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_scaling)
+    return 1.0 / (cfg.rope_theta ** (jnp.arange(0, dr, 2, dtype=jnp.float32)
+                                     / dr))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(dn + dr)^-0.5, times YaRN's mscale(mscale_all_dim)^2 where
+    configured (DeepSeek-V2-Lite: 192^-0.5 * 1.26080^2 = 0.114722)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 def _group_q(q, hk):
@@ -412,3 +455,81 @@ def decode_attention(params, x1, cache, pos, cfg, *, window=None):
     if quant:
         return y, cache
     return y, {"k": ck, "v": cv}
+
+
+def _mla_attend_gather(q, latent, block_table, pos, scale, v_width):
+    """The jnp oracle of the latent mode: gather each row's whole logical
+    latent view (B, MB*bs, Dp), score every head's absorbed query against
+    it under the causal mask, and take the first `v_width` columns as V."""
+    b, w = q.shape[:2]
+    bs = latent.shape[2]
+    mb = block_table.shape[1]
+    view = latent[block_table][:, :, 0].reshape(b, mb * bs, -1)
+    view = view.astype(q.dtype)
+    valid = jnp.arange(mb * bs)[None, None, :] <= pos[:, :, None]
+    s = jnp.einsum("bwhd,bkd->bhwk", q, view,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid[:, None], s, NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhwk,bkc->bwhc", p.astype(view.dtype),
+                      view[..., :v_width])
+
+
+def mla_span_attention_paged(params, x, pool, block_table, ctx_lens, q_lens,
+                             cfg, *, impl=None):
+    """`span_attention_paged` for multi-head latent attention, in absorbed
+    form: one form serves prefill chunks and decode alike.
+
+    Each position caches one latent entry [norm(c), rope(k_pe)]
+    (C + dr wide, zero-padded to the pool leaf's lane width) in
+    pool["latent"] (NB, 1, bs, Dp) — no per-head K or V. Head h's query
+    is [q_nope_h @ W_UK_h^T, rope(q_pe_h)], so its score against a
+    position is the dot with that position's entry (every head shares
+    the one latent "head", G = H); its output is the softmax-weighted
+    sum of the entries' first C columns (the latent itself is V), times
+    W_UV_h, then wo. Scale: `mla_softmax_scale`. Rotations act on the
+    interleaved pairs (2i, 2i+1) of the rope dims, which is DeepSeek's
+    de-interleave-then-rotate-half up to the order of dims in the dot.
+    """
+    from repro.runtime.kvblocks import span_slots
+
+    b, w, _ = x.shape
+    h = cfg.num_heads
+    dn, dr, c = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    latent = pool["latent"]
+    bs, dp = latent.shape[2], latent.shape[3]
+
+    q = apply_linear(x, params["wq"]).reshape(b, w, h, dn + dr)
+    kv = apply_linear(x, params["wkv_a"])                     # (B, W, C+dr)
+    ckv = rmsnorm(kv[..., :c], params["kv_norm"]["gamma"], cfg.norm_eps)
+    pos = ctx_lens[:, None] + jnp.arange(w)[None, :]            # (B, W)
+    inv = mla_rope_freqs(cfg)
+    q_pe = rotate_pairs(q[..., dn:], pos, inv)
+    k_pe = rotate_pairs(kv[..., None, c:], pos, inv)[:, :, 0]
+    with jax.named_scope("mla.absorb"):
+        q_lat = jnp.einsum("bwhn,hnc->bwhc", q[..., :dn],
+                           params["w_uk"].astype(q.dtype))
+    pad = dp - (c + dr)
+    qf = jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype),
+                          jnp.zeros((b, w, h, pad), q_lat.dtype)], -1)
+    entry = jnp.concatenate([ckv, k_pe.astype(ckv.dtype),
+                             jnp.zeros((b, w, pad), ckv.dtype)], -1)
+
+    blk, off = span_slots(block_table, ctx_lens, q_lens, w, bs)
+    latent = latent.at[blk, 0, off].set(entry.astype(latent.dtype))
+    scale = mla_softmax_scale(cfg)
+    impl = impl or _paged_impl(cfg)
+    if impl == "kernel":
+        from repro.kernels.paged_attention import latent_paged_attention
+
+        o = latent_paged_attention(qf, latent, block_table, ctx_lens,
+                                   q_lens, scale=scale, v_width=c,
+                                   interpret=not kops.on_tpu())
+    else:
+        o = _mla_attend_gather(qf, latent, block_table, pos, scale, c)
+    with jax.named_scope("mla.absorb"):
+        o = jnp.einsum("bwhc,hcv->bwhv", o.astype(q.dtype),
+                       params["w_uv"].astype(q.dtype))
+    y = apply_linear(o.reshape(b, w, h * cfg.v_head_dim), params["wo"],
+                     reduce_tp=True)
+    return y, {"latent": latent}

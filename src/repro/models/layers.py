@@ -143,10 +143,48 @@ def rope_freqs(head_dim: int, theta: float, rotary_pct: float = 1.0):
     return inv, rot
 
 
+def yarn_inv_freq(dim: int, theta: float, scaling):
+    """YaRN rotary frequencies (DeepSeek-V2's `DeepseekV2YarnRotaryEmbedding`):
+    frequency i keeps f_i = theta^(-2i/dim) below the correction range,
+    takes f_i / factor above it, and blends linearly inside it. The range
+    is [floor(d(beta_fast)), ceil(d(beta_slow))], clipped to [0, dim - 1],
+    where d(b) = dim * ln(orig / (2 pi b)) / (2 ln theta) is the index
+    whose wavelength fits b times into the original context."""
+    import math
+
+    def corr(rot):
+        return dim * math.log(scaling.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(corr(scaling.beta_fast)), 0)
+    hi = min(math.ceil(corr(scaling.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return f / scaling.factor * (1.0 - keep) + f * keep
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN attention-temperature factor 0.1 * mscale * ln(factor) + 1."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def apply_rope(x, positions, theta: float, rotary_pct: float = 1.0):
     """x: (..., S, H, Dh); positions: broadcastable to (..., S)."""
     hd = x.shape[-1]
     inv, rot = rope_freqs(hd, theta, rotary_pct)
+    return rotate_pairs(x, positions, inv, rot)
+
+
+def rotate_pairs(x, positions, inv, rot: int | None = None):
+    """Rotate the interleaved pairs (2i, 2i+1) of x's first `rot` dims by
+    positions * inv[i]. x: (..., S, H, Dh)."""
+    rot = x.shape[-1] if rot is None else rot
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, rot/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     xr, xp = x[..., :rot], x[..., rot:]

@@ -4,7 +4,9 @@ Layouts
   dense  — scan over L identical (attn + MLP) blocks; local/global
            alternating archs (Gemma2) scan over *pairs* so each member of
            the pair keeps a static window;
-  moe    — scan over L (attn + MoE) blocks;
+  moe    — scan over L (attn + MoE) blocks; a model whose first
+           `moe.first_dense` layers are dense (DeepSeek) scans those
+           (params["dense_layers"]) first, then the MoE blocks;
   ssm    — scan over L Mamba1 blocks (attention-free);
   hybrid — Zamba2: scan over groups of `hybrid_period` Mamba2 blocks, with
            one *shared-weight* transformer block invoked after each group
@@ -47,11 +49,13 @@ def init_params(key, cfg):
         p["lm_head"] = jax.random.normal(keys[1], (d, cfg.vocab_size),
                                          dtype) * d ** -0.5
 
+    attn_init = attn.mla_init if cfg.is_mla else attn.attn_init
+
     def dense_block(k):
         ks = jax.random.split(k, 2)
         return {
             "ln1": init_norm(cfg.norm, d, dtype),
-            "attn": attn.attn_init(ks[0], cfg, dtype),
+            "attn": attn_init(ks[0], cfg, dtype),
             "ln2": init_norm(cfg.norm, d, dtype),
             "mlp": mlp_init(ks[1], d, cfg.d_ff, cfg.mlp_act, dtype),
         }
@@ -60,7 +64,7 @@ def init_params(key, cfg):
         ks = jax.random.split(k, 2)
         return {
             "ln1": init_norm(cfg.norm, d, dtype),
-            "attn": attn.attn_init(ks[0], cfg, dtype),
+            "attn": attn_init(ks[0], cfg, dtype),
             "ln2": init_norm(cfg.norm, d, dtype),
             "moe": moe_mod.moe_init(ks[1], cfg, dtype),
         }
@@ -78,7 +82,10 @@ def init_params(key, cfg):
         else:
             p["layers"] = _stack_init(dense_block, keys[2], L)
     elif cfg.layout == "moe":
-        p["layers"] = _stack_init(moe_block, keys[2], L)
+        fd = cfg.first_dense
+        if fd:
+            p["dense_layers"] = _stack_init(dense_block, keys[4], fd)
+        p["layers"] = _stack_init(moe_block, keys[2], L - fd)
     elif cfg.layout == "ssm":
         p["layers"] = _stack_init(
             functools.partial(mamba_block, version=cfg.ssm.version), keys[2], L)
@@ -154,8 +161,36 @@ def _maybe_remat(cfg, fn):
     return jax.checkpoint(fn)
 
 
+def _layer_stacks(params) -> list:
+    """The scan-stacked layer groups in order: a MoE model's leading
+    dense layers ("dense_layers"), then "layers"."""
+    return [params[k] for k in ("dense_layers", "layers") if k in params]
+
+
+def _split_layers(tree, params):
+    """A per-layer (L, ...) cache split as `_layer_stacks` groups it."""
+    if "dense_layers" not in params:
+        return [tree]
+    fd = jax.tree_util.tree_leaves(params["dense_layers"])[0].shape[0]
+    return [jax.tree_util.tree_map(lambda x: x[:fd], tree),
+            jax.tree_util.tree_map(lambda x: x[fd:], tree)]
+
+
+def _join_layers(parts):
+    return jax.tree_util.tree_map(lambda *x: jnp.concatenate(x, 0), *parts)
+
+
+def _rectangular(cfg):
+    if cfg.is_mla:
+        raise NotImplementedError(
+            "latent attention (MLA) serves through InferenceEngine.serve "
+            "only; the rectangular forward/prefill/decode paths hold "
+            "per-head K and V")
+
+
 def forward(params, inputs, cfg, *, ssm_engine="sequential"):
     """Returns (final hidden (B,S,D), aux_loss)."""
+    _rectangular(cfg)
     h = embed(params, inputs, cfg)
     L = cfg.num_layers
 
@@ -179,8 +214,11 @@ def forward(params, inputs, cfg, *, ssm_engine="sequential"):
                 h, a = _dense_body(cfg, h, lp, window=cfg.attn_window)
                 return (h, aux + a), None
 
-            (h, aux), _ = jax.lax.scan(_maybe_remat(cfg, body), (h, 0.0),
-                                       params["layers"])
+            carry = (h, 0.0)
+            for stack in _layer_stacks(params):
+                carry, _ = jax.lax.scan(_maybe_remat(cfg, body), carry,
+                                        stack)
+            h, aux = carry
     elif cfg.layout == "ssm":
         def body(h, lp):
             return _mamba_body(cfg, h, lp, engine=ssm_engine), None
@@ -297,6 +335,7 @@ def prefill(params, inputs, cfg, *, max_len=None, cache_dtype=None,
     passing its index as a traced value keeps the bucket's compilation
     shared across every real length inside it.
     """
+    _rectangular(cfg)
     h = embed(params, inputs, cfg)
     L = cfg.num_layers
     s = h.shape[1]
@@ -328,8 +367,11 @@ def prefill(params, inputs, cfg, *, max_len=None, cache_dtype=None,
             def body(h, lp):
                 return dense_with_kv(h, lp, cfg.attn_window)
 
-            h, kv = jax.lax.scan(_maybe_remat(cfg, body), h, params["layers"])
-            cache = {"kv": kv}
+            kvs = []
+            for stack in _layer_stacks(params):
+                h, kv = jax.lax.scan(_maybe_remat(cfg, body), h, stack)
+                kvs.append(kv)
+            cache = {"kv": _join_layers(kvs)}
     elif cfg.layout == "ssm":
         pre = (mamba.mamba1_prefill if cfg.ssm.version == 1
                else mamba.mamba2_prefill)
@@ -376,7 +418,7 @@ def prefill(params, inputs, cfg, *, max_len=None, cache_dtype=None,
 
 
 def unified_step(params, pool, block_tables, ctx_lens, q_lens, inputs, cfg,
-                 verify_width: int = 0):
+                 verify_width: int = 0, *, token_budget: int | None = None):
     """ONE token-budget serving step over a blocked KV pool: every active
     row advances by a span of `q_lens[r]` tokens — a prefill chunk, a
     single decode token, or nothing — in a single forward pass.
@@ -387,11 +429,12 @@ def unified_step(params, pool, block_tables, ctx_lens, q_lens, inputs, cfg,
     runtime.kvblocks.init_paged_cache leaves (L, NB, Hk, bs, *), scanned
     over layers exactly like the monolithic cache. Returns
     (logits (B, 1, V) f32 at each row's LAST valid span position,
-    updated pool) — exactly the logits that sample the row's next token
-    when its span completes the prompt or decodes. Idle rows compute
-    garbage the caller discards; shapes are static in (B, W, MB) so the
-    one jitted step covers the whole serve loop regardless of
-    admissions, evictions, or the prefill/decode mix (W is bucketed to a
+    updated pool, experts_hit) — the logits are exactly those that
+    sample the row's next token when its span completes the prompt or
+    decodes. Idle rows compute garbage the caller discards; shapes are
+    static in (B, W, MB) so the one jitted step covers the whole serve
+    loop regardless of admissions, evictions, or the prefill/decode mix
+    (W is bucketed to a
     power of two by the driver, so at most O(log budget) shapes exist,
     and W == 1 — the decode-only steady state — is exactly the classic
     one-token paged decode). The row-major span layout keeps the KV
@@ -429,27 +472,50 @@ def unified_step(params, pool, block_tables, ctx_lens, q_lens, inputs, cfg,
     psums per step, the only collectives. With no TP axis bound the
     reduce_tp flag is inert and this is the single-device step
     unchanged.
+
+    Latent attention (`cfg.is_mla`) runs `attn.mla_span_attention_paged`
+    over the latent pool. MoE layers route through `moe.moe_routed`:
+    valid rows only (from q_lens), dropless, compacted into
+    `token_budget` slots where the caller knows the step's budget.
+    A model whose first layers are dense scans them first, over its own
+    group of the pool (`kvblocks.init_paged_cache`).
+    The third output is the number of experts that got at least one
+    valid row, summed over the MoE layers (int32 scalar; 0 without MoE).
     """
     from repro.runtime.kvblocks import check_paged_support
 
     check_paged_support(cfg)
     h = embed(params, inputs, cfg, pos0=ctx_lens)
+    span_attn = (attn.mla_span_attention_paged if cfg.is_mla
+                 else attn.span_attention_paged)
 
-    def body(h, xs):
+    def body(carry, xs):
+        h, hits = carry
         lp, pl = xs
         hn = apply_norm(h, lp["ln1"], cfg.norm, cfg.norm_eps)
-        a, pl = attn.span_attention_paged(lp["attn"], hn, pl, block_tables,
-                                          ctx_lens, q_lens, cfg)
+        a, pl = span_attn(lp["attn"], hn, pl, block_tables, ctx_lens,
+                          q_lens, cfg)
         h = h + a
         hn = apply_norm(h, lp["ln2"], cfg.norm, cfg.norm_eps)
         if "moe" in lp:
-            y, _ = moe_mod.moe_apply(lp["moe"], hn, cfg)
+            y, hit = moe_mod.moe_routed(lp["moe"], hn, q_lens, cfg,
+                                        token_budget)
+            hits = hits + hit
         else:
             y = mlp_apply(hn, lp["mlp"], cfg.mlp_act)
         h = h + y
-        return h, pl
+        return (h, hits), pl
 
-    h, pool = jax.lax.scan(body, h, (params["layers"], pool))
+    carry = (h, jnp.zeros((), jnp.int32))
+    if "dense_layers" in params:
+        carry, pool0 = jax.lax.scan(body, carry, (params["dense_layers"],
+                                                  pool["dense_layers"]))
+        carry, pool1 = jax.lax.scan(body, carry, (params["layers"],
+                                                  pool["layers"]))
+        pool = {"dense_layers": pool0, "layers": pool1}
+    else:
+        carry, pool = jax.lax.scan(body, carry, (params["layers"], pool))
+    h, hits = carry
     h = apply_norm(h, params["final_norm"], cfg.norm, cfg.norm_eps)
     last = jnp.maximum(q_lens - 1, 0)[:, None, None]      # (B, 1, 1)
     h1 = jnp.take_along_axis(h, jnp.broadcast_to(
@@ -459,11 +525,12 @@ def unified_step(params, pool, block_tables, ctx_lens, q_lens, inputs, cfg,
             raise ValueError(f"verify_width {verify_width} exceeds span "
                              f"width {h.shape[1]}")
         h1 = jnp.concatenate([h[:, :verify_width], h1], axis=1)
-    return logits_for(params, h1, cfg), pool
+    return logits_for(params, h1, cfg), pool, hits
 
 
 def serve_step(params, pool, block_tables, step_buf, prev, recent,
-               stop_seqs, cfg, *, sample: bool = False, stop: bool = False):
+               stop_seqs, cfg, *, sample: bool = False, stop: bool = False,
+               token_budget: int | None = None):
     """One fused serving dispatch: `unified_step` plus the logits→token
     path (sampling) and device stop evaluation, all in one jit.
 
@@ -488,7 +555,11 @@ def serve_step(params, pool, block_tables, step_buf, prev, recent,
     Returns (toks (B, 1) int32, finished (B,) int32, recent, pool).
     `finished` flags rows whose emission this step completed the
     request (eos / stop sequence / max_tokens); the engine reads it off
-    the already-pipelined readback — no extra host sync.
+    the already-pipelined readback — no extra host sync. A MoE model's
+    toks are (B, `step_token_cols`) = (B, 2): column 1 carries the
+    step's `experts_hit` (every row the same), read back with the tokens
+    at no extra sync.
+    token_budget: the step's static token budget (MoE routing slots).
     """
     from repro.runtime import sampling as smp
 
@@ -497,8 +568,9 @@ def serve_step(params, pool, block_tables, step_buf, prev, recent,
     ctx_lens, q_lens, use_prev = meta[:, 0], meta[:, 1], meta[:, 2]
     tokens = tokens.at[:, 0].set(
         jnp.where(use_prev.astype(bool), prev[:, 0], tokens[:, 0]))
-    logits, pool = unified_step(params, pool, block_tables, ctx_lens,
-                                q_lens, tokens, cfg)
+    logits, pool, hits = unified_step(params, pool, block_tables, ctx_lens,
+                                      q_lens, tokens, cfg,
+                                      token_budget=token_budget)
     last = logits[:, -1]
     if sample:
         sp = smp.unpack_meta(step_buf)
@@ -513,12 +585,29 @@ def serve_step(params, pool, block_tables, step_buf, prev, recent,
         fin = smp.finished_mask(toks[:, 0], recent, sp, stop_seqs)
     else:
         fin = jnp.zeros((toks.shape[0],), jnp.int32)
+    if step_token_cols(cfg) > 1:
+        toks = jnp.concatenate(
+            [toks, jnp.broadcast_to(hits, toks.shape)], axis=1)
     return toks, fin, recent, pool
+
+
+def step_budget(cfg, token_budget: int | None) -> int | None:
+    """The static token budget `serve_step` is compiled for: the call's
+    budget where MoE layers size their routing slots from it, else None
+    (a dense step does not read it)."""
+    return token_budget if cfg.layout == "moe" else None
+
+
+def step_token_cols(cfg) -> int:
+    """Columns of `serve_step`'s tokens: the sampled token, then, for a
+    model with MoE layers, the step's `experts_hit`."""
+    return 2 if cfg.layout == "moe" else 1
 
 
 def decode_step(params, cache, inputs, pos, cfg):
     """One decode step. inputs: (B, 1) tokens or (B, 1, D) embeds.
     Returns (logits (B, 1, V) f32, new cache)."""
+    _rectangular(cfg)
     h = embed(params, inputs, cfg, pos0=pos)
     L = cfg.num_layers
 
@@ -562,8 +651,12 @@ def decode_step(params, cache, inputs, pos, cfg):
                 h, kvc = dense_step(h, lp, kvc, cfg.attn_window)
                 return h, kvc
 
-            h, kv = jax.lax.scan(body, h, (params["layers"], cache["kv"]))
-            cache = {"kv": kv}
+            kvs = []
+            for stack, kvc in zip(_layer_stacks(params),
+                                  _split_layers(cache["kv"], params)):
+                h, kv = jax.lax.scan(body, h, (stack, kvc))
+                kvs.append(kv)
+            cache = {"kv": _join_layers(kvs)}
     elif cfg.layout == "ssm":
         def body(h, xs):
             lp, mc = xs

@@ -63,6 +63,9 @@ def check_paged_support(cfg) -> None:
         raise NotImplementedError(
             "paged KV decode does not support windowed or local/global "
             "attention yet — their rolling caches are already O(window)")
+    if cfg.is_mla and getattr(cfg, "kv_cache_bits", 16) != 16:
+        raise NotImplementedError(
+            "the latent (MLA) pool holds the model dtype only, not int8")
 
 
 def blocks_needed(prompt_len: int, max_tokens: int, block_size: int) -> int:
@@ -231,19 +234,35 @@ def prefix_digests(tokens, block_size: int, fingerprint: bytes = b"") \
 
 def copy_block(pool, src, dst):
     """Copy-on-write primitive: duplicate physical block `src` into `dst`
-    across every pool leaf (codes and int8 scale planes alike). jit-safe
-    with traced src/dst, and TP-safe — the copy moves along the block
-    axis 1 while `pool_pspecs` shards the KV-head axis 2, so each shard
-    copies exactly its own head slice."""
-    return {key: leaf.at[:, dst].set(leaf[:, src])
-            for key, leaf in pool.items()}
+    across every pool leaf (codes and int8 scale planes alike, and every
+    layer group of `init_paged_cache`). jit-safe with traced src/dst, and
+    TP-safe — the copy moves along the block axis 1 while `pool_pspecs`
+    shards the KV-head axis 2, so each shard copies exactly its own head
+    slice."""
+    import jax
+
+    return jax.tree_util.tree_map(lambda leaf: leaf.at[:, dst].set(
+        leaf[:, src]), pool)
+
+
+def latent_width(cfg) -> int:
+    """Lanes of one latent pool entry: kv_lora_rank + qk_rope_head_dim,
+    zero-padded to whole 128-lane tiles (the width the TPU lays the
+    leaf out in anyway, so the padding costs no memory)."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
 def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None):
     """Physical pool arrays for every layer: {"k","v"} of shape
     (L, num_blocks, Hk, block_size, Dh), plus {"ks","vs"} f32 scale planes
     (L, num_blocks, Hk, block_size, 1) when cfg.kv_cache_bits == 8 (same
-    int8 code + scale convention as attention.init_kv_cache).
+    int8 code + scale convention as attention.init_kv_cache). A latent
+    (MLA) model instead holds one entry per token and layer,
+    {"latent": (L, num_blocks, 1, block_size, latent_width(cfg))}: the
+    normed latent and the roped shared key, no per-head K or V. A MoE
+    model whose first layers are dense splits the pool by layer group,
+    {"dense_layers": ..., "layers": ...}, as its parameters are split,
+    so each group's scan takes its own slice of layers.
 
     The paged-attention kernel reads one (block_size, Dh) tile per
     (block, head). Those are the leaves' own last two dims, which the
@@ -254,15 +273,26 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int, dtype=None):
 
     check_paged_support(cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
-    L, hk, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    shape = (L, num_blocks, hk, block_size, hd)
-    if getattr(cfg, "kv_cache_bits", 16) == 8:
-        sshape = (L, num_blocks, hk, block_size, 1)
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "ks": jnp.ones(sshape, jnp.float32),
-                "vs": jnp.ones(sshape, jnp.float32)}
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    hk, hd = cfg.num_kv_heads, cfg.head_dim
+
+    def group(L):
+        if cfg.is_mla:
+            return {"latent": jnp.zeros(
+                (L, num_blocks, 1, block_size, latent_width(cfg)), dtype)}
+        shape = (L, num_blocks, hk, block_size, hd)
+        if getattr(cfg, "kv_cache_bits", 16) == 8:
+            sshape = (L, num_blocks, hk, block_size, 1)
+            return {"k": jnp.zeros(shape, jnp.int8),
+                    "v": jnp.zeros(shape, jnp.int8),
+                    "ks": jnp.ones(sshape, jnp.float32),
+                    "vs": jnp.ones(sshape, jnp.float32)}
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    fd = cfg.first_dense
+    if fd:
+        return {"dense_layers": group(fd),
+                "layers": group(cfg.num_layers - fd)}
+    return group(cfg.num_layers)
 
 
 def pool_pspecs(cfg):

@@ -223,8 +223,9 @@ def speculative_step(params, draft_params, pool, block_tables, step_buf,
     d = prev
     for i in range(k):
         ql = (spec_lens > i).astype(jnp.int32)
-        dlogits, pool = tfm.unified_step(draft_params, pool, block_tables,
-                                         ctx_lens + i, ql, d, cfg)
+        dlogits, pool, _ = tfm.unified_step(draft_params, pool,
+                                            block_tables, ctx_lens + i, ql,
+                                            d, cfg)
         d = jnp.argmax(dlogits[:, -1], axis=-1)[:, None].astype(jnp.int32)
         drafts.append(d)
 
@@ -236,8 +237,9 @@ def speculative_step(params, draft_params, pool, block_tables, step_buf,
         spec_cols = jnp.arange(k)[None, :] < spec_lens[:, None]  # (B, k)
         tokens = tokens.at[:, 1:k + 1].set(
             jnp.where(spec_cols, draft_mat, tokens[:, 1:k + 1]))
-    logits, pool = tfm.unified_step(params, pool, block_tables, ctx_lens,
-                                    q_lens, tokens, cfg, verify_width=k + 1)
+    logits, pool, _ = tfm.unified_step(params, pool, block_tables, ctx_lens,
+                                       q_lens, tokens, cfg,
+                                       verify_width=k + 1)
     full_toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)    # (B, k+2)
     if sample:
         # sampled rows (temperature > 0; never drafting, so n_acc will

@@ -79,8 +79,9 @@ def _record_buffers(monkeypatch, engine, speculative):
                             keeping(step_fn(k, sample), 4))
     else:
         unified = engine._unified_fn
-        monkeypatch.setattr(engine, "_unified_fn", lambda sample, stop:
-                            keeping(unified(sample, stop), 3))
+        monkeypatch.setattr(engine, "_unified_fn",
+                            lambda sample, stop, budget=None:
+                            keeping(unified(sample, stop, budget), 3))
     return bufs
 
 

@@ -245,3 +245,78 @@ def test_itera_w4_serving_step_compiles(topo, monkeypatch, tp):
     assert _kernels(compiled) == {"lowrank_qmm", "paged_attention"}
     assert pool["k"].sharding.shard_shape(pool["k"].shape)[2] == \
         cfg.num_kv_heads // tp
+
+
+@pytest.mark.parametrize("w", [1, 32, 256], ids=["decode", "span32",
+                                                 "span256"])
+def test_latent_paged_attention_compiles(one_chip, monkeypatch, w):
+    """The latent mode at DeepSeek-V2-Lite's widths (16 heads sharing one
+    640-lane entry, V its first 512 columns, bs 16) over 32 rows of
+    432-entry tables, at decode and at span widths that take one and
+    several query tiles, under a scoped VMEM limit of exactly the
+    kernel's own model of a program's working set."""
+    from repro.kernels import paged_attention as pa
+
+    b, h, dp, vw, bs, mb = 32, 16, 640, 512, 16, 432
+    p, wt = pa.latent_tile(w, h, dp, vw, bs, mb, itemsize=2)
+    assert (wt == w) == (w <= 32)
+    _within_modeled_vmem(monkeypatch, pa.latent_vmem_bytes(
+        wt * h, dp, vw, p, bs, itemsize=2))
+    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
+    compiled = jax.jit(lambda *a: pa.latent_paged_attention(
+        *a, scale=0.114722, v_width=vw, interpret=False)).lower(
+        _spec(one_chip, (b, w, h, dp), jnp.bfloat16),
+        _spec(one_chip, (b * mb + 1, 1, bs, dp), jnp.bfloat16),
+        i32((b, mb)), i32((b,)), i32((b,))).compile()
+    assert _kernels(compiled) == {"paged_attention"}
+
+
+@pytest.mark.parametrize("kn", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_lowrank_gmm_compiles(one_chip, kn):
+    """The grouped expert kernel at DeepSeek-V2-Lite's expert widths (64
+    experts, rank 256, W1 packed along R, W2 packed along N only where N
+    is whole pack tiles) over 256 tokens x 6 routed pairs."""
+    k, n = kn
+    e, r, m = 64, 256, 256 * 6
+    w2_packed = n % 256 == 0
+
+    def f(x, v1, s1, v2, s2, off):
+        lr = LowRankQ(QuantizedTensor(v1, s1, 4, 0, packed=True),
+                      QuantizedTensor(v2, s2, 4, 1, packed=w2_packed))
+        return ops.lrgmm(x, lr, off, interpret=False, out_dtype=jnp.bfloat16)
+
+    compiled = jax.jit(f).lower(
+        _spec(one_chip, (m, k), jnp.bfloat16),
+        _spec(one_chip, (e, k, r // 2), jnp.int8),
+        _spec(one_chip, (e, 1, r), jnp.float32),
+        _spec(one_chip, (e, r, n // 2 if w2_packed else n), jnp.int8),
+        _spec(one_chip, (e, r, 1), jnp.float32),
+        _spec(one_chip, (e + 1,), jnp.int32)).compile()
+    assert "%lowrank_gmm." in compiled.as_text()
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("stack", ["carrier", "packed", "bf16"])
+def test_grouped_expert_linear_compiles(one_chip, stack, precision):
+    """An expert stack that is not ITERA, through the routed layer's
+    grouped product at DeepSeek-V2-Lite's gate/up widths: `qgmm` for a
+    quantized stack (carrier or packed W4 codes), `ragged_dot` for a
+    bf16 one; at the default matmul precision and at "highest", which a
+    configuration may ask for."""
+    from repro.models import moe
+
+    e, k, n, m = 64, 2048, 1408, 256 * 6
+
+    def f(x, v, s, off):
+        w = (v if stack == "bf16" else
+             QuantizedTensor(v, s, 4, 0, packed=stack == "packed"))
+        with jax.default_matmul_precision(precision):
+            return moe._grouped(x, w, off)
+
+    v = (_spec(one_chip, (e, k, n), jnp.bfloat16) if stack == "bf16" else
+         _spec(one_chip, (e, k, n // 2 if stack == "packed" else n),
+               jnp.int8))
+    jax.jit(f).lower(_spec(one_chip, (m, k), jnp.bfloat16), v,
+                     _spec(one_chip, (e, 1, n), jnp.float32),
+                     _spec(one_chip, (e + 1,), jnp.int32)).compile()
